@@ -13,7 +13,13 @@ redistributable, so ground truth comes from the generator):
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import io
+import json
+import os
+import subprocess
+import sys
 import time
 
 import jax
@@ -197,3 +203,55 @@ def write_csv(path: str, rows: list[dict]):
         w.writeheader()
         for r in rows:
             w.writerow(r)
+
+
+def device_info() -> dict:
+    """The devices this process runs on, as JAX reports them — every
+    printed benchmark row names them, so no number is mistaken for one
+    taken on another device."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}
+
+
+def emit_row(row: dict) -> None:
+    """Print one result row as ``ROW <json>``, naming its devices."""
+    print("ROW " + json.dumps({**row, **device_info()}), flush=True)
+
+
+def run_multi_device(module: str, args: list, part, n: int = 4
+                     ) -> list[dict]:
+    """Run a table's ``n``-device ``part`` and return the rows it emitted.
+
+    Held to the CPU (``JAX_PLATFORMS=cpu``), the part runs in a child
+    (``python -m module *args``) with ``n`` forced host devices, because a
+    process that has touched JAX cannot change its device count. On an
+    accelerator host it runs in this process over the real devices — a
+    child could not reach a chip this process holds — and refuses when
+    there are fewer than ``n``."""
+    from repro.launch.mesh import cpu_requested
+
+    if cpu_requested():
+        env = dict(os.environ)
+        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+        env["PYTHONPATH"] = os.pathsep.join(
+            p for p in ("src", ".", env.get("PYTHONPATH", "")) if p)
+        proc = subprocess.run(
+            [sys.executable, "-m", module, *map(str, args)],
+            capture_output=True, text=True, timeout=3600, env=env)
+        if proc.returncode != 0:
+            raise RuntimeError(f"{module} child failed:\n"
+                               f"{proc.stderr[-3000:]}")
+        out = proc.stdout
+    else:
+        info = device_info()
+        if info["device_count"] < n:
+            raise RuntimeError(
+                f"{module} needs {n} devices; this {info['platform']} host "
+                f"has {info['device_count']}")
+        buf = io.StringIO()
+        with contextlib.redirect_stdout(buf):
+            part()
+        out = buf.getvalue()
+    return [json.loads(line[4:]) for line in out.splitlines()
+            if line.startswith("ROW ")]

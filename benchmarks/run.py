@@ -2,15 +2,19 @@
 """Benchmark runner: executes every per-table module, writes one CSV row
 per (table, configuration) as ``name,us_per_call,derived`` where
 us_per_call is the per-document ingest cost and ``derived`` carries the
-table's headline metric. Full rows also land in benchmarks/results/*.csv.
+table's headline metric. Full rows also land in benchmarks/results/*.csv,
+each naming the platform, device kind and device count it ran on. A
+table that fails is reported and the rest still run, but the runner
+then exits non-zero.
 """
 from __future__ import annotations
 
 import os
+import sys
 import time
 import traceback
 
-from benchmarks.common import write_csv
+from benchmarks.common import device_info, write_csv
 
 FAST = os.environ.get("REPRO_BENCH_FAST", "0") == "1"
 
@@ -79,6 +83,9 @@ def main() -> None:
     # every table land in one registry, dumped next to the CSV results
     obs.enable(metrics=True, trace=False)
     all_rows = []
+    failed = []
+    dev = device_info()
+    print(f"# device: {dev}")
     print("name,us_per_call,derived")
     for tname, fn in _tables():
         t0 = time.time()
@@ -87,7 +94,9 @@ def main() -> None:
         except Exception:
             traceback.print_exc()
             print(f"{tname}/ERROR,0,0")
+            failed.append(tname)
             continue
+        rows = [{**dev, **row} for row in rows]  # child rows keep theirs
         all_rows.extend(rows)
         write_csv(f"benchmarks/results/{tname}.csv", rows)
         for row in rows:
@@ -100,6 +109,9 @@ def main() -> None:
         reg.dump_json("benchmarks/results/metrics.json")
         print("# metrics snapshot -> benchmarks/results/metrics.json",
               flush=True)
+    if failed:
+        print(f"# FAILED tables: {', '.join(failed)}", flush=True)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -25,10 +25,9 @@ with the right env and parses its JSON rows — safe to call from
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
 import sys
+
+from benchmarks.common import emit_row, run_multi_device
 
 DIM = 64
 NPROBE = 16
@@ -114,7 +113,8 @@ def _child(n_batches: int, batch: int, seed: int):
                  "store_shrink": 1.0})
 
     for (d, m) in ((1, 4), (4, 1)):
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((d, m), ("data", "model"))
         eng = ShardedEngine(cfg, mesh, jax.random.key(seed), warmup=warm,
                             reconcile_every=10**9)  # reconcile per round
         r = _eval_engine(eng, n_batches=n_batches, batch=batch, seed=seed)
@@ -132,23 +132,13 @@ def _child(n_batches: int, batch: int, seed: int):
         assert abs(row["recall10"] - base) < 0.1, (row["variant"], base,
                                                   row["recall10"])
     for row in rows:
-        print("ROW " + json.dumps(row), flush=True)
+        emit_row(row)
 
 
 def run(n_batches: int = 24, batch: int = 128, seed: int = 0) -> list[dict]:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in ("src", ".", env.get("PYTHONPATH", "")) if p)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.table15_sharded", "--child",
-         str(n_batches), str(batch), str(seed)],
-        capture_output=True, text=True, timeout=3600, env=env)
-    if proc.returncode != 0:
-        raise RuntimeError(f"table15 child failed:\n{proc.stderr[-3000:]}")
-    rows = [json.loads(line[4:]) for line in proc.stdout.splitlines()
-            if line.startswith("ROW ")]
+    rows = run_multi_device(
+        "benchmarks.table15_sharded", ["--child", n_batches, batch, seed],
+        lambda: _child(n_batches, batch, seed))
     for row in rows:
         row.pop("recall_rounds", None)
     return rows

@@ -30,11 +30,10 @@ the ingested stream and the snapshot being served.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
 import sys
 import time
+
+from benchmarks.common import emit_row, run_multi_device
 
 DIM = 64
 QPS = 32
@@ -224,7 +223,8 @@ def _serving_child(n_batches: int, batch: int, seed: int):
     cfg = _config(k=512, depth=16)   # reconcile-heavy serving state
     scfg = ServerConfig(max_batch=QPS, max_wait_ms=0.0, topk=TOPK,
                         two_stage=True, nprobe=NPROBE)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 2), ("data", "model"))
     gap = 500.0                      # open-loop arrival period, ms
     rows = []
 
@@ -250,7 +250,7 @@ def _serving_child(n_batches: int, batch: int, seed: int):
     assert asy["p99_answer_ms"] < base["p99_answer_ms"], \
         (asy["p99_answer_ms"], base["p99_answer_ms"])
     for row in rows:
-        print("ROW " + json.dumps(row), flush=True)
+        emit_row(row)
 
 
 def _delta_child(n_batches: int, batch: int, seed: int):
@@ -261,7 +261,8 @@ def _delta_child(n_batches: int, batch: int, seed: int):
 
     cfg = _config(k=512, depth=16)
     stream = _stream(seed)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2), ("data", "model"))
     engines = {
         "full": ShardedEngine(cfg, mesh, jax.random.key(seed),
                               reconcile_every=10**9),
@@ -311,31 +312,21 @@ def _delta_child(n_batches: int, batch: int, seed: int):
     speedup = float(np.mean(times["full"])) / float(np.mean(times["delta"]))
     for name in engines:
         ms = float(np.mean(times[name]))
-        print("ROW " + json.dumps({
+        emit_row({
             "table": "table16", "variant": f"reconcile_{name}",
             "publish_ms": round(ms, 3),
             "dirty_frac": round(float(np.mean(dirty)), 4) if dirty else 1.0,
             "publish_speedup": round(speedup, 2) if name == "delta" else 1.0,
-            "bit_identical": True}), flush=True)
+            "bit_identical": True})
 
 
 def _run_child(mode: str, n_batches: int, batch: int, seed: int,
                n_devices: int = 4) -> list[dict]:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = \
-        f"--xla_force_host_platform_device_count={n_devices}"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in ("src", ".", env.get("PYTHONPATH", "")) if p)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.table16_async_serving", mode,
-         str(n_batches), str(batch), str(seed)],
-        capture_output=True, text=True, timeout=3600, env=env)
-    if proc.returncode != 0:
-        raise RuntimeError(f"table16 child {mode} failed:\n"
-                           f"{proc.stderr[-3000:]}")
-    return [json.loads(line[4:]) for line in proc.stdout.splitlines()
-            if line.startswith("ROW ")]
+    part = {"--serving-child": _serving_child,
+            "--delta-child": _delta_child}[mode]
+    return run_multi_device(
+        "benchmarks.table16_async_serving", [mode, n_batches, batch, seed],
+        lambda: part(n_batches, batch, seed), n=n_devices)
 
 
 def run(n_batches: int = 24, batch: int = 512, seed: int = 0) -> list[dict]:

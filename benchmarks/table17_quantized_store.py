@@ -34,10 +34,9 @@ with the right env and parses its JSON rows — safe to call from
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
 import sys
+
+from benchmarks.common import emit_row, run_multi_device
 
 DIM = 384          # paper dim: int8@4x-depth bytes ~= fp32@1x-depth bytes
 NPROBE = 16
@@ -144,7 +143,8 @@ def _child(n_batches: int, batch: int, seed: int):
     for label, depth, dtype in (("fp32_d16", DEPTH, "fp32"),
                                 ("int8_d64", 4 * DEPTH, "int8")):
         cfg = _config(depth, dtype)
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 4), ("data", "model"))
         eng = ShardedEngine(cfg, mesh, jax.random.key(seed), warmup=warm,
                             reconcile_every=10**9)  # reconcile per round
         rec, lat = _eval_engine(eng, n_batches=n_batches, batch=batch,
@@ -165,23 +165,14 @@ def _child(n_batches: int, batch: int, seed: int):
     gain = by["int8_d64"]["recall10"] - by["fp32_d16"]["recall10"]
     for row in rows:
         row["recall_gain_int8_4x"] = round(gain, 4)
-        print("ROW " + json.dumps(row), flush=True)
+        emit_row(row)
 
 
 def run(n_batches: int = 24, batch: int = 128, seed: int = 0) -> list[dict]:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in ("src", ".", env.get("PYTHONPATH", "")) if p)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.table17_quantized_store",
-         "--child", str(n_batches), str(batch), str(seed)],
-        capture_output=True, text=True, timeout=3600, env=env)
-    if proc.returncode != 0:
-        raise RuntimeError(f"table17 child failed:\n{proc.stderr[-3000:]}")
-    rows = [json.loads(line[4:]) for line in proc.stdout.splitlines()
-            if line.startswith("ROW ")]
+    rows = run_multi_device(
+        "benchmarks.table17_quantized_store",
+        ["--child", n_batches, batch, seed],
+        lambda: _child(n_batches, batch, seed))
     for row in rows:
         row.pop("recall_rounds", None)
     return rows

@@ -62,10 +62,9 @@ pattern as tables 15-17).
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
 import sys
+
+from benchmarks.common import emit_row, run_multi_device
 
 B = 50             # paper Table 2 microbatch
 DIM = 384
@@ -226,7 +225,8 @@ def _sharded_rows(n_batches: int, rounds: int, seed: int):
                     np.float32)
     idss = np.arange(n_batches * N_DATA * B,
                      dtype=np.int32).reshape(n_batches, -1)
-    mesh = jax.make_mesh((N_DATA,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((N_DATA,), ("data",))
     rows = []
     for store_dtype in ("fp32", "int8"):
         cfg = _paper_cfg(store_dtype)
@@ -333,23 +333,14 @@ def _child(n_batches: int, rounds: int, seed: int):
         sp = by[("fused_stream", dtype)]["speedup_vs_staged"]
         assert sp >= 1.5, (dtype, sp, "fused ingest speedup below 1.5x")
     for row in rows:
-        print("ROW " + json.dumps(row), flush=True)
+        emit_row(row)
 
 
 def run(n_batches: int = 24, rounds: int = 7, seed: int = 0) -> list[dict]:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in ("src", ".", env.get("PYTHONPATH", "")) if p)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.table18_ingest_throughput",
-         "--child", str(n_batches), str(rounds), str(seed)],
-        capture_output=True, text=True, timeout=3600, env=env)
-    if proc.returncode != 0:
-        raise RuntimeError(f"table18 child failed:\n{proc.stderr[-3000:]}")
-    return [json.loads(line[4:]) for line in proc.stdout.splitlines()
-            if line.startswith("ROW ")]
+    return run_multi_device(
+        "benchmarks.table18_ingest_throughput",
+        ["--child", n_batches, rounds, seed],
+        lambda: _child(n_batches, rounds, seed))
 
 
 if __name__ == "__main__":

@@ -44,10 +44,9 @@ pattern as tables 15-18).
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
 import sys
+
+from benchmarks.common import emit_row, run_multi_device
 
 Q = 50             # paper serving microbatch
 DIM = 384
@@ -221,7 +220,8 @@ def _sharded_rows(reps: int, seed: int):
     from repro.engine.sharded import ShardedEngine
 
     rng = np.random.default_rng(seed)
-    mesh = jax.make_mesh((N_MODEL,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((N_MODEL,), ("model",))
     rows = []
     for store_dtype in ("fp32", "int8"):
         cfg = _paper_cfg(store_dtype)
@@ -270,23 +270,13 @@ def _sharded_rows(reps: int, seed: int):
 def _child(reps: int, seed: int):
     rows = _single_device_rows(reps, seed) + _sharded_rows(reps, seed)
     for row in rows:
-        print("ROW " + json.dumps(row), flush=True)
+        emit_row(row)
 
 
 def run(reps: int = 40, seed: int = 0) -> list[dict]:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in ("src", ".", env.get("PYTHONPATH", "")) if p)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.table19_serve_fusion",
-         "--child", str(reps), str(seed)],
-        capture_output=True, text=True, timeout=3600, env=env)
-    if proc.returncode != 0:
-        raise RuntimeError(f"table19 child failed:\n{proc.stderr[-3000:]}")
-    return [json.loads(line[4:]) for line in proc.stdout.splitlines()
-            if line.startswith("ROW ")]
+    return run_multi_device(
+        "benchmarks.table19_serve_fusion", ["--child", reps, seed],
+        lambda: _child(reps, seed))
 
 
 if __name__ == "__main__":

@@ -28,11 +28,12 @@ CI overload gate.
 """
 from __future__ import annotations
 
-import json
 import sys
 import time
 
 import numpy as np
+
+from benchmarks.common import emit_row
 
 DIM = 64
 TOPK = 10
@@ -258,5 +259,5 @@ if __name__ == "__main__":
     else:
         out = run()
     for row in out:
-        print("ROW " + json.dumps(row), flush=True)
+        emit_row(row)
     print("TABLE20-OVERLOAD-OK", flush=True)

@@ -34,11 +34,12 @@ staleness).
 """
 from __future__ import annotations
 
-import json
 import sys
 import time
 
 import numpy as np
+
+from benchmarks.common import emit_row
 
 DIM = 64
 TOPK = 10
@@ -257,5 +258,5 @@ if __name__ == "__main__":
     else:
         out = run()
     for row in out:
-        print("ROW " + json.dumps(row), flush=True)
+        emit_row(row)
     print("TABLE21-HOTSET-CACHE-OK", flush=True)

@@ -31,13 +31,14 @@ the CI crash-recovery gate.
 """
 from __future__ import annotations
 
-import json
 import shutil
 import sys
 import tempfile
 import time
 
 import numpy as np
+
+from benchmarks.common import emit_row
 
 DIM = 48
 TOPK = 10
@@ -289,5 +290,5 @@ if __name__ == "__main__":
     else:
         out = run()
     for row in out:
-        print("ROW " + json.dumps(row), flush=True)
+        emit_row(row)
     print("TABLE22-RECOVERY-OK", flush=True)

@@ -24,21 +24,6 @@ from repro.core import clustering, heavy_hitter
 from repro.kernels.common import NEG_INF
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool):
-    """``shard_map`` across jax versions: the replication-check kwarg was
-    renamed ``check_rep`` -> ``check_vma``; dispatch on whichever the
-    installed jax accepts."""
-    import inspect
-
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # older jax
-        from jax.experimental.shard_map import shard_map
-    params = inspect.signature(shard_map).parameters
-    flag = "check_vma" if "check_vma" in params else "check_rep"
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **{flag: check_vma})
-
-
 def merge_clusters(state: clustering.ClusterState, axis) -> clustering.ClusterState:
     """Count-weighted centroid merge across ``axis`` (inside shard_map)."""
     wsum = jax.lax.psum(state.centroids * state.counts[:, None], axis)
@@ -98,11 +83,13 @@ def make_distributed_merge(cfg, mesh, data_axis_names: tuple[str, ...]):
 
     def merge_stacked(stacked_states):
         """stacked_states: pytree with leading dim = #data shards."""
-        fn = compat_shard_map(
+        # jitted: JAX's eager shard_map path re-shards each output in its
+        # own program and trips over outputs XLA proves replicated
+        fn = jax.jit(jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(axis), stacked_states),),
             out_specs=jax.tree.map(lambda _: P(axis), stacked_states),
-            check_vma=False)
+            check_vma=False))
         return fn(stacked_states)
 
     return merge_stacked

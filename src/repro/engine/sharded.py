@@ -64,8 +64,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import clustering, heavy_hitter, index as index_lib, pipeline
 from repro.distributed import sharding as shard_rules
-from repro.distributed.collectives import (compat_shard_map,
-                                           distributed_rerank_topk,
+from repro.distributed.collectives import (distributed_rerank_topk,
                                            distributed_serve_topk)
 from repro.engine import stages
 from repro.engine.engine import ServingSnapshot, ingest_impl
@@ -225,7 +224,7 @@ class ShardedEngine:
             return jax.tree.map(lambda a: a[None], new_state)
 
         def run(stacked, x, ids):
-            fn = compat_shard_map(
+            fn = jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(shard_rules.leading_axis_pspecs(stacked, data_axis),
                           axis, axis),
@@ -269,7 +268,7 @@ class ShardedEngine:
                 shard_rules.leading_axis_pspecs(docstore.init(cfg.store),
                                                 model_axis),
                 P(), P(), P())
-            fn = compat_shard_map(
+            fn = jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(shard_rules.leading_axis_pspecs(
                     stacked, data_axis),),
@@ -338,7 +337,7 @@ class ShardedEngine:
                 self._abstract_index(), None)
             store_specs = shard_rules.leading_axis_pspecs(
                 docstore.init(cfg.store), model_axis)
-            fn = compat_shard_map(
+            fn = jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(shard_rules.leading_axis_pspecs(stacked, data_axis),
                           P(), index_specs, P(), store_specs, P(), P()),
@@ -366,7 +365,7 @@ class ShardedEngine:
                 scales=scales, depth=depth)
 
         def run(qn, routes, store):
-            fn = compat_shard_map(
+            fn = jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(), P(),
                           shard_rules.leading_axis_pspecs(store, model_axis)),
@@ -397,7 +396,7 @@ class ShardedEngine:
                 depth=depth)
 
         def run(qr, qn, vectors, valid, route_labels, store):
-            fn = compat_shard_map(
+            fn = jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(), P(), P(), P(), P(),
                           shard_rules.leading_axis_pspecs(store, model_axis)),

@@ -7,7 +7,10 @@ to the TPU memory hierarchy:
   phase 1 (this kernel)   — grid (Q/bq, N/bn); each step computes the
       [bq, bn] fp32 score tile on the MXU and reduces it **in VMEM** to the
       tile's local top-k via k iterations of (row-max, mask). Only
-      [bq, k] winners per tile are written back — the [Q, N] score matrix
+      [bq, k] winners per tile are written back, into a [N/bn, Q, k]
+      output whose per-step block (bq, k) spans the array's full last
+      dim — the TPU's block-shape rule for a k that is not a multiple of
+      128 — the [Q, N] score matrix
       never reaches HBM (a 1M-candidate fp32 score row is 4 MB/query; at
       serve_bulk batch 262k that matrix would be 1 TB).
   phase 2 (ops wrapper)   — jax.lax.top_k over the (N/bn)*k surviving
@@ -85,17 +88,21 @@ def mips_topk_pallas(
             pl.BlockSpec((1, bn), lambda i, n: (0, n)),
         ],
         out_specs=[
-            pl.BlockSpec((bq, k), lambda i, n: (i, n)),
-            pl.BlockSpec((bq, k), lambda i, n: (i, n)),
+            pl.BlockSpec((None, bq, k), lambda i, n: (n, i, 0)),
+            pl.BlockSpec((None, bq, k), lambda i, n: (n, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Qp, nblocks * k), jnp.float32),
-            jax.ShapeDtypeStruct((Qp, nblocks * k), jnp.int32),
+            jax.ShapeDtypeStruct((nblocks, Qp, k), jnp.float32),
+            jax.ShapeDtypeStruct((nblocks, Qp, k), jnp.int32),
         ],
         interpret=interpret_mode(),
     )(qp, xp, bias)
 
-    # Phase 2: merge tile winners (nblocks*k candidates/query — tiny).
+    # Phase 2: merge tile winners (nblocks*k candidates/query — tiny), laid
+    # out block-major per query so lax.top_k's lowest-index tie-break
+    # prefers the lower block, as a single top-k over all N would.
+    sc = sc.transpose(1, 0, 2).reshape(Qp, nblocks * k)
+    ids = ids.transpose(1, 0, 2).reshape(Qp, nblocks * k)
     top_sc, pos = jax.lax.top_k(sc[:Q], k)
     top_id = jnp.take_along_axis(ids[:Q], pos, axis=1)
     return top_sc, top_id.astype(jnp.int32)

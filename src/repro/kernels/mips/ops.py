@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels.common import use_pallas_default
 from repro.kernels.mips.ref import mips_topk_ref
 
@@ -24,6 +25,8 @@ def mips_topk(
     assert k >= 1 and k <= index.shape[0], "k must be in [1, N]"
     if use_pallas is None:
         use_pallas = use_pallas_default()
+    # trace-time only: counts (re)compilations per dispatch path
+    obs.count_kernel_trace("mips", "pallas" if use_pallas else "ref")
     if use_pallas:
         from repro.kernels.mips.mips import mips_topk_pallas
 

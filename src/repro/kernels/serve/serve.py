@@ -13,31 +13,39 @@ registers, and the routed ring tiles are then pulled in by explicit
 ``pltpu.make_async_copy`` DMAs *driven by the in-kernel route values* —
 routes computed inside a kernel cannot feed a BlockSpec index map, which
 is exactly why the staged split existed. Serve-side HBM traffic is one
-pass over the routed ring tiles (+ their bias/scale rows) plus the query
+pass over the routed ring tiles (+ one slot row each) plus the query
 block and the (tiny, VMEM-resident) prototype index.
 
-int8 ring tiles ride the same DMA path as fp32: the tile and its
-[1, depth] scale row are copied into VMEM, the tile is widened to fp32
+int8 ring tiles ride the same DMA path as fp32: the tile and its slot
+row of per-slot scales are copied into VMEM, the tile is widened to fp32
 *inside the kernel*, scored on the MXU with fp32 accumulation, and the
 per-slot scale applied to the score row ((q·e)·s == q·(s·e)) — no fp32
-candidate tensor ever exists in HBM. When the ring depth misses the
+candidate tensor ever exists in HBM. The slot row also carries liveness:
+an additive 0 / NEG_INF bias for fp32 rings, a NaN scale for dead int8
+slots. It is lane-padded to a whole (1, 128k) tile, the only per-cluster
+slice Mosaic lets a DMA take. When the ring depth misses the
 dtype's sublane multiple (8 fp32 / 32 int8) only the VMEM staging tile is
 padded — the pad rows are zeroed in-kernel and never DMAd, so the store
 is NOT copied host-side and the padded rows cost zero HBM bytes (the
 staged rerank kernel pads the store itself in that case).
+
+Candidate scores live in a [nprobe, bq, dp] scratch: each (query, probe)
+score row is stored as a whole [1, dp] row of its probe's plane. A ring
+depth below 128 lanes is thus never a lane slice of a wider row, which
+Mosaic refuses (lane slices must be 128-aligned).
 
 Grid: (Q // bq,). Per step: [bq, d] query block; route scores in
 ``bk``-column chunks of the VMEM-resident [cap, d] index; nprobe
 iterations of (row-max, min-id mask) — identical tie-breaking to
 ``lax.top_k`` — yield routes; per (query, probe) the ring tile is DMAd in
 ``bd``-row chunks and scored; the final top-k extraction runs k
-iterations of (max, min-id) over the [bq, nprobe * depth] candidate
-scores in VMEM, emitting exactly the staged composition's
+iterations of (max, min-id) across the nprobe [bq, dp] candidate-score
+planes in VMEM, emitting exactly the staged composition's
 (scores, pos, routes) with the same dead -> -1 semantics. (bq, bk, bd)
 are the autotuner's tile space (``kernels.tuning``).
 
 VMEM working set per step: bq*d (queries, x2) + cap*d (index) + bq*cap
-(route scores) + bq*nprobe*dp (candidate scores) + dp*d (tile staging)
+(route scores) + nprobe*bq*dp (candidate scores) + dp*d (tile staging)
 fp32 words + the tiny bias/scale rows. Paper defaults (bq=8, cap<=256,
 d=384, nprobe=8, depth=16) stay under ~1 MB of the ~16 MB/core VMEM.
 """
@@ -57,15 +65,10 @@ _SENTINEL = 2**31 - 2  # padded-slot id: loses every min-id tie
 
 
 def _serve_kernel(qr_ref, qn_ref, idx_ref, ibias_ref, lbl_ref,
-                  embs_hbm, bias_hbm, *rest,
+                  embs_hbm, slot_hbm, sc_ref, pos_ref, rt_ref, rs_scr,
+                  cd_scr, e_scr, r_scr, sem, *,
                   capp: int, C: int, depth: int, dp: int, P: int, k: int,
                   bq: int, bk: int, bd: int, quantized: bool):
-    if quantized:
-        scale_hbm, sc_ref, pos_ref, rt_ref, rs_scr, cd_scr, e_scr, b_scr, \
-            s_scr, sem = rest
-    else:
-        sc_ref, pos_ref, rt_ref, rs_scr, cd_scr, e_scr, b_scr, sem = rest
-
     # ---- stage 1: prototype route scores, bk columns at a time, into the
     # VMEM scratch — the [Q, cap] score matrix never reaches HBM.
     qr = qr_ref[...].astype(jnp.float32)   # [bq, d]
@@ -100,11 +103,11 @@ def _serve_kernel(qr_ref, qn_ref, idx_ref, ibias_ref, lbl_ref,
 
     # ---- stage 2: DMA each routed ring tile into VMEM and score it. The
     # sublane pad rows of the staging tile (dp > depth) are zeroed once per
-    # step and never DMAd: zero rows score 0, then the NEG_INF bias pad
-    # kills them — same additive-bias masking as the rerank kernel.
+    # step (the whole tile: a sub-tile row slice is not a legal store) and
+    # never DMAd: zero rows score 0, then the NEG_INF bias pad kills them
+    # — same additive-bias masking as the rerank kernel.
     if dp > depth:
-        e_scr[depth:, :] = jnp.zeros((dp - depth, e_scr.shape[1]),
-                                     e_scr.dtype)
+        e_scr[...] = jnp.zeros(e_scr.shape, e_scr.dtype)
     for i in range(bq):
         qi = qn[i:i + 1, :]                # [1, d]
         for j in range(P):
@@ -116,41 +119,42 @@ def _serve_kernel(qr_ref, qn_ref, idx_ref, ibias_ref, lbl_ref,
                     e_scr.at[pl.ds(t * bd, bd)], sem)
                 cp.start()
                 cp.wait()
-            cpb = pltpu.make_async_copy(bias_hbm.at[c], b_scr.at[0], sem)
-            cpb.start()
-            cpb.wait()
-            if quantized:
-                cps = pltpu.make_async_copy(scale_hbm.at[c], s_scr.at[0],
-                                            sem)
-                cps.start()
-                cps.wait()
+            cpr = pltpu.make_async_copy(slot_hbm.at[c], r_scr, sem)
+            cpr.start()
+            cpr.wait()
             # int8 tiles widen to fp32 HERE, in VMEM — fp32 MXU accumulate
             e = e_scr[...].astype(jnp.float32)       # [dp, d]
             s = jax.lax.dot_general(
                 qi, e, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [1, dp]
-            if quantized:
-                s = s * s_scr[...]         # per-slot dequant scale
-            s = s + b_scr[...]             # live/pad mask as additive bias
+            row = r_scr[...][:, :dp]       # this cluster's slot row
+            if quantized:                  # dequant scale, NaN = dead slot
+                s = s * row
+                s = jnp.where(s == s, s, NEG_INF)
+            else:
+                s = s + row                # live mask as additive bias
             s = jnp.where(r < 0, NEG_INF, s)  # whole tile dead if no route
-            cd_scr[i:i + 1, j * dp:(j + 1) * dp] = s
+            cd_scr[j, i:i + 1, :] = s      # whole row of probe j's plane
 
-    # ---- final top-k over the [bq, P*dp] candidate scores: k iterations
-    # of (max, min-id) with ids = j*depth + slot (pads get a sentinel), ==
-    # lax.top_k over the staged [Q, P*depth] score table, tie-break
-    # included.
-    flat = cd_scr[...]
-    col = jax.lax.broadcasted_iota(jnp.int32, flat.shape, 1)
-    jj, local = col // dp, col % dp
-    ids = jnp.where(local < depth, jj * depth + local, jnp.int32(_SENTINEL))
+    # ---- final top-k over the nprobe [bq, dp] candidate planes: k
+    # iterations of (max, min-id) with ids = j*depth + slot (pads get a
+    # sentinel), == lax.top_k over the staged [Q, P*depth] score table,
+    # tie-break included.
+    planes = [cd_scr[j] for j in range(P)]
+    local = jax.lax.broadcasted_iota(jnp.int32, (bq, dp), 1)
+    ids = [jnp.where(local < depth, j * depth + local, jnp.int32(_SENTINEL))
+           for j in range(P)]
     for t in range(k):
-        m = jnp.max(flat, axis=1)
-        a = jnp.min(jnp.where(flat >= m[:, None], ids,
-                              jnp.int32(2**31 - 1)), axis=1)
+        m = functools.reduce(jnp.maximum,
+                             [jnp.max(p, axis=1) for p in planes])
+        a = functools.reduce(jnp.minimum, [
+            jnp.min(jnp.where(p >= m[:, None], pid, jnp.int32(2**31 - 1)),
+                    axis=1) for p, pid in zip(planes, ids)])
         sc_ref[:, t] = m
         pos_ref[:, t] = a
-        flat = jnp.where(ids == a[:, None], NEG_INF, flat)
+        planes = [jnp.where(pid == a[:, None], NEG_INF, p)
+                  for p, pid in zip(planes, ids)]
 
 
 @functools.partial(jax.jit,
@@ -182,6 +186,7 @@ def serve_topk_pallas(
         "int8 ring buffers require per-slot scales (and fp32 forbids them)"
     sublane = SUBLANE_I8 if quantized else SUBLANE_F32
     dp = round_up(max(depth, 1), sublane)
+    dl = round_up(dp, LANE)
 
     bq = round_up(min(bq, max(1, Q)), SUBLANE_F32)
     bk = min(bk, round_up(max(cap, 1), LANE))
@@ -197,32 +202,35 @@ def serve_topk_pallas(
                     constant_values=NEG_INF)[None, :]          # [1, capp]
     lblp = jnp.pad(route_labels.astype(jnp.int32), (0, capp - cap),
                    constant_values=-1)[None, :]                # [1, capp]
-    # liveness as an additive bias row, sublane-padded with NEG_INF; the
-    # store itself is never padded or copied (only its VMEM staging tile).
-    bias = pad_dim(jnp.where(live, 0.0, NEG_INF).astype(jnp.float32), 1,
-                   sublane, value=NEG_INF)                     # [C, dp]
-    operands = [qrp, qnp_, vp, ibias, lblp, embs, bias]
+    # One per-slot row per cluster carries liveness: an additive bias
+    # (0 live, NEG_INF dead or pad) for fp32 rings, the dequant scale
+    # (NaN dead or pad) for int8 rings — one DMA per routed cluster. The
+    # rows are lane-padded to [C, 1, dl], so one cluster's row is a whole
+    # (1, 128k) tile, the only DMA source slice Mosaic accepts. The store
+    # itself is never padded or copied (only its VMEM staging tile).
+    if quantized:
+        slot_rows = jnp.where(live, scales.astype(jnp.float32), jnp.nan)
+    else:
+        slot_rows = jnp.where(live, 0.0, NEG_INF).astype(jnp.float32)
+    slot_rows = pad_dim(slot_rows, 1, LANE,
+                        value=jnp.nan if quantized else NEG_INF)[:, None]
+    operands = [qrp, qnp_, vp, ibias, lblp, embs, slot_rows]
     in_specs = [
         pl.BlockSpec((bq, d), lambda i: (i, 0)),
         pl.BlockSpec((bq, d), lambda i: (i, 0)),
         pl.BlockSpec((capp, d), lambda i: (0, 0)),
         pl.BlockSpec((1, capp), lambda i: (0, 0)),
         pl.BlockSpec((1, capp), lambda i: (0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),   # ring tiles: manual DMA
-        pl.BlockSpec(memory_space=pltpu.ANY),   # bias rows: manual DMA
+        pl.BlockSpec(memory_space=pl.ANY),   # ring tiles: manual DMA
+        pl.BlockSpec(memory_space=pl.ANY),   # slot rows: manual DMA
     ]
     scratch = [
         pltpu.VMEM((bq, capp), jnp.float32),       # route scores
-        pltpu.VMEM((bq, nprobe * dp), jnp.float32),  # candidate scores
+        pltpu.VMEM((nprobe, bq, dp), jnp.float32),  # candidate scores
         pltpu.VMEM((dp, d), embs.dtype),           # ring-tile staging
-        pltpu.VMEM((1, dp), jnp.float32),          # bias row staging
+        pltpu.VMEM((1, dl), jnp.float32),          # slot row staging
+        pltpu.SemaphoreType.DMA,
     ]
-    if quantized:
-        scales_p = pad_dim(scales.astype(jnp.float32), 1, sublane)
-        operands.append(scales_p)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
-        scratch.append(pltpu.VMEM((1, dp), jnp.float32))  # scale staging
-    scratch.append(pltpu.SemaphoreType.DMA)
 
     kernel = functools.partial(
         _serve_kernel, capp=capp, C=C, depth=depth, dp=dp, P=nprobe, k=k,
@@ -254,7 +262,7 @@ def modeled_dma_bytes(Q: int, d: int, cap: int, C: int, depth: int,
                       nprobe: int, k: int, quantized: bool) -> int:
     """Exact serve-side HBM traffic of one fused-kernel call: everything
     the program streams (query blocks, the VMEM-resident index + its
-    valid/label rows, the per-(query, probe) ring-tile/bias/scale DMAs)
+    valid/label rows, the per-(query, probe) ring-tile and slot-row DMAs)
     plus its outputs. This is the DMA ledger of the kernel above — kept
     analytic because interpret-mode HLO does not model the TPU DMA
     pattern — and the number ``kernel_bench``/table19 check against the
@@ -263,9 +271,8 @@ def modeled_dma_bytes(Q: int, d: int, cap: int, C: int, depth: int,
     itemsize = 1 if quantized else 4
     q_bytes = 2 * Q * d * 4                       # qr + qn blocks
     index_bytes = cap * d * 4 + 2 * cap * 4       # vectors + ibias + labels
-    tile = depth * d * itemsize + depth * 4       # ring tile + bias row
-    if quantized:
-        tile += depth * 4                         # scale row
+    # ring tile + its lane-padded slot row (bias, or scale for int8)
+    tile = depth * d * itemsize + round_up(depth, LANE) * 4
     out_bytes = Q * k * 8 + Q * nprobe * 4
     return q_bytes + index_bytes + Q * nprobe * tile + out_bytes
 

@@ -3,11 +3,16 @@
 Stands up a RAG server over a simulated stream and drives a Zipf query
 workload against the live index, printing latency/recall stats.
 
+``--dim`` defaults to the paper's embedding width (384, SBERT/MiniLM);
+pass a smaller one for a quick CPU run.
+
 ``--mesh D,M`` (e.g. ``--mesh 2,2``) serves from the sharded engine
 instead: the stream is data-sharded D ways for ingest and the document
-store is cluster-sharded M ways for two-stage retrieval. On a CPU host
-the D*M devices are forced via ``--xla_force_host_platform_device_count``
-(which is why the mesh flag is parsed before jax initializes).
+store is cluster-sharded M ways for two-stage retrieval. The mesh is
+built over the real devices; only a run held to the CPU
+(``JAX_PLATFORMS=cpu``) forces D*M host devices via
+``--xla_force_host_platform_device_count`` (which is why the mesh flag is
+parsed before jax initializes).
 
 ``--async`` serves through ``serve.runtime.AsyncServer``: a background
 thread ingests the stream and publishes snapshots every
@@ -62,7 +67,7 @@ def _parse_mesh(spec: str) -> tuple[int, int]:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--stream", default="nyt")
-    ap.add_argument("--dim", type=int, default=64)
+    ap.add_argument("--dim", type=int, default=384)
     ap.add_argument("--batches", type=int, default=50)
     ap.add_argument("--batch", type=int, default=256)
     ap.add_argument("--qps", type=int, default=32, help="queries per batch")
@@ -134,15 +139,21 @@ def main():
                     help="serving-report line every N stream batches")
     args = ap.parse_args()
 
-    # Device forcing must precede the first jax device query.
+    # Device forcing (CPU runs only) must precede the first jax device
+    # query.
     mesh_shape = _parse_mesh(args.mesh) if args.mesh else None
     if mesh_shape is not None:
-        from repro.launch.mesh import force_host_devices
+        from repro.launch.mesh import cpu_requested, force_host_devices
 
-        force_host_devices(mesh_shape[0] * mesh_shape[1])
+        if cpu_requested():
+            force_host_devices(mesh_shape[0] * mesh_shape[1])
 
     import jax
     import numpy as np
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro import obs
     from repro.configs.streaming_rag import paper_pipeline_config
@@ -202,9 +213,9 @@ def main():
     engine = None
     if mesh_shape is not None:
         from repro.engine.sharded import ShardedEngine
-        from repro.launch.mesh import make_streaming_mesh
+        from repro.launch.mesh import make_mesh
 
-        mesh = make_streaming_mesh(*mesh_shape)
+        mesh = make_mesh(mesh_shape, ("data", "model"))
         engine = ShardedEngine(
             cfg, mesh, jax.random.key(0), warmup=warm,
             # async: the runtime's publish cadence drives (delta) reconcile

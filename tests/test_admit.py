@@ -208,9 +208,9 @@ def test_stages_admit_equals_screen_assign_quantize():
 def test_engine_fused_kernel_matches_reference_engine(store_dtype):
     """Full single-device ingest with the fused Pallas admission
     (use_pallas=True, interpret mode) vs the staged reference engine:
-    every PipelineState leaf — centroids, counters, index, ring rows,
-    scales — bit-identical across a stream with a ragged batch, and
-    two-stage query results identical."""
+    every integer and boolean PipelineState leaf — counters, labels, ids,
+    masks, int8 ring rows — identical across a stream with a ragged batch,
+    every float leaf to 1e-6, and two-stage query doc ids identical."""
     cfg_r = _small_cfg(store_dtype=store_dtype, use_pallas=False)
     cfg_p = _small_cfg(store_dtype=store_dtype, use_pallas=True)
     s = make_stream("iot", dim=32)
@@ -230,9 +230,15 @@ def test_engine_fused_kernel_matches_reference_engine(store_dtype):
             jax.tree_util.tree_flatten_with_path(st_p)[0]):
         if jnp.issubdtype(jnp.asarray(a).dtype, jax.dtypes.prng_key):
             a, b_ = jax.random.key_data(a), jax.random.key_data(b_)
-        np.testing.assert_array_equal(
-            np.asarray(a), np.asarray(b_),
-            err_msg=jax.tree_util.keystr(path))
+        a, b_ = np.asarray(a), np.asarray(b_)
+        if np.issubdtype(a.dtype, np.floating):
+            # the kernel and the staged composition are different programs:
+            # their fp32 similarities (e.g. rep_sims) may differ by an ulp
+            np.testing.assert_allclose(a, b_, rtol=1e-6, atol=1e-6,
+                                       err_msg=jax.tree_util.keystr(path))
+        else:
+            np.testing.assert_array_equal(
+                a, b_, err_msg=jax.tree_util.keystr(path))
 
     q = jnp.asarray(s.queries(6)["embedding"])
     out_r = pipeline.query(cfg_r, st_r, q, 5, two_stage=True, nprobe=4)
@@ -243,10 +249,20 @@ def test_engine_fused_kernel_matches_reference_engine(store_dtype):
 def test_sharded_engine_fused_kernel_parity_4dev():
     """ShardedEngine ingest with the fused Pallas admission vs the staged
     reference on a forced 4-device (2 data x 2 model) mesh: shard-local
-    states and the published snapshot bit-identical, ragged global batches
-    included."""
+    states and the published snapshot match — integer and boolean leaves
+    exactly, float leaves to 1e-6 (different programs: fp32 similarities
+    may differ by an ulp) — ragged global batches included."""
     body = """
         import dataclasses
+
+        def same(a, c, msg=""):
+            a, c = np.asarray(a), np.asarray(c)
+            if np.issubdtype(a.dtype, np.floating):
+                np.testing.assert_allclose(a, c, rtol=1e-6, atol=1e-6,
+                                           err_msg=msg)
+            else:
+                np.testing.assert_array_equal(a, c, err_msg=msg)
+
         from repro.configs.streaming_rag import paper_pipeline_config
         from repro.data.streams import make_stream
         from repro.engine.sharded import ShardedEngine
@@ -259,7 +275,8 @@ def test_sharded_engine_fused_kernel_parity_4dev():
         cfg_p = dataclasses.replace(
             cfg_r, clus=dataclasses.replace(cfg_r.clus, use_pallas=True))
         stream = make_stream("iot", dim=32)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         eng_r = ShardedEngine(cfg_r, mesh, jax.random.key(0),
                               reconcile_every=100)
         eng_p = ShardedEngine(cfg_p, mesh, jax.random.key(0),
@@ -276,9 +293,7 @@ def test_sharded_engine_fused_kernel_parity_4dev():
             if jnp.issubdtype(jnp.asarray(a).dtype, jax.dtypes.prng_key):
                 a = jax.random.key_data(jnp.asarray(a))
                 c = jax.random.key_data(jnp.asarray(c))
-            np.testing.assert_array_equal(
-                np.asarray(a), np.asarray(c),
-                err_msg=jax.tree_util.keystr(path))
+            same(a, c, jax.tree_util.keystr(path))
         print("LOCAL-PARITY-OK")
 
         snap_r, snap_p = eng_r.reconcile(), eng_p.reconcile()
@@ -286,7 +301,7 @@ def test_sharded_engine_fused_kernel_parity_4dev():
                                          snap_r.store)),
                         jax.tree.leaves((snap_p.index, snap_p.route_labels,
                                          snap_p.store))):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+            same(a, c)
         print("SNAPSHOT-PARITY-OK")
     """
     out = _run_in_4_device_subprocess(body)

@@ -26,8 +26,9 @@ def _run_in_multi_device_subprocess(body: str):
 def test_counter_merge_across_shards_matches_union():
     out = _run_in_multi_device_subprocess("""
         from repro.core import heavy_hitter as hh
-        from repro.distributed.collectives import compat_shard_map as shard_map
-        mesh = jax.make_mesh((8,), ("data",))
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         cfg = hh.HHConfig(capacity=32, admit_prob=1.0)
         rng = np.random.default_rng(0)
         streams = rng.integers(0, 12, (8, 64)).astype(np.int32)
@@ -39,7 +40,7 @@ def test_counter_merge_across_shards_matches_union():
             m = merge_counters(cfg, s, "data")
             return jax.tree.map(lambda x: x[None], m)
 
-        with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+        with jax.set_mesh(mesh):
             fn = shard_map(shard_fn, mesh=mesh, in_specs=P("data"),
                            out_specs=P("data"), check_vma=False)
             merged = fn(jnp.asarray(streams))
@@ -63,8 +64,9 @@ def test_weighted_centroid_merge_and_compressed_psum():
         from repro.core import clustering as C
         from repro.distributed.collectives import merge_clusters
         from repro.distributed.compression import compressed_psum
-        from repro.distributed.collectives import compat_shard_map as shard_map
-        mesh = jax.make_mesh((8,), ("data",))
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(1)
         cents = rng.normal(size=(8, 4, 16)).astype(np.float32)
         counts = rng.integers(0, 10, (8, 4)).astype(np.float32)
@@ -111,8 +113,9 @@ def test_distributed_mips_matches_exact():
     out = _run_in_multi_device_subprocess("""
         from repro.distributed.collectives import distributed_mips_topk
         from repro.kernels.mips.ref import mips_topk_ref
-        from repro.distributed.collectives import compat_shard_map as shard_map
-        mesh = jax.make_mesh((8,), ("model",))
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("model",))
         rng = np.random.default_rng(2)
         N, d, k = 512, 16, 10
         X = rng.normal(size=(N, d)).astype(np.float32)
@@ -142,7 +145,8 @@ def test_elastic_checkpoint_restore_onto_mesh():
         from repro.train.checkpoint import CheckpointManager
         from jax.sharding import NamedSharding
         import tempfile
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         tree = {"w": jnp.arange(64.0).reshape(8, 8)}
         with tempfile.TemporaryDirectory() as d:
             mgr = CheckpointManager(d)
@@ -168,7 +172,9 @@ def test_distributed_pipeline_merge_end_to_end():
         from repro.data.streams import make_stream
         from repro.distributed.collectives import make_distributed_merge
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((8,), ("data",))
         cfg = paper_pipeline_config(dim=32, k=32, capacity=16,
                                     update_interval=64, alpha=-1.0)
         stream = make_stream("iot", dim=32)

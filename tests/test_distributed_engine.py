@@ -54,7 +54,8 @@ def test_sharded_engine_matches_single_device_oracle():
                                     update_interval=48, alpha=-1.0,
                                     store_depth=4)
         stream = make_stream("iot", dim=32)
-        mesh = jax.make_mesh((D, M), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((D, M), ("data", "model"))
         eng = ShardedEngine(cfg, mesh, jax.random.key(0),
                             reconcile_every=100)
         batches = [stream.next_batch(64) for _ in range(8)]
@@ -164,7 +165,8 @@ def test_delta_reconcile_bit_identical_to_full():
                                     update_interval=48, alpha=-1.0,
                                     store_depth=4)
         stream = make_stream("iot", dim=32)
-        mesh = jax.make_mesh((D, M), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((D, M), ("data", "model"))
         full = ShardedEngine(cfg, mesh, jax.random.key(0),
                              reconcile_every=10**9)
         delta = ShardedEngine(cfg, mesh, jax.random.key(0),
@@ -236,7 +238,8 @@ def test_ragged_batch_pads_match_padded_replay():
                                     update_interval=48, alpha=-1.0,
                                     store_depth=4)
         stream = make_stream("iot", dim=32)
-        mesh = jax.make_mesh((D, M), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((D, M), ("data", "model"))
         eng = ShardedEngine(cfg, mesh, jax.random.key(0),
                             reconcile_every=100)
         sizes = [64, 61, 64, 39]              # two ragged batches
@@ -304,7 +307,9 @@ def test_distributed_merge_carries_ring_buffers():
         from repro.distributed.collectives import make_distributed_merge
         from repro.store import docstore
 
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((4,), ("data",))
         cfg = paper_pipeline_config(dim=32, k=32, capacity=16,
                                     update_interval=64, alpha=-1.0,
                                     store_depth=4)

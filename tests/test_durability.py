@@ -381,7 +381,8 @@ def test_sharded_crash_recovery_bit_identical():
                                     update_interval=48, alpha=-1.0,
                                     store_depth=4)
         scfg = ServerConfig(max_batch=8, topk=5, two_stage=True, nprobe=4)
-        mesh = jax.make_mesh((D, M), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((D, M), ("data", "model"))
         stream = make_stream("iot", dim=32)
         batches = [stream.next_batch(64) for _ in range(8)]
 

@@ -110,8 +110,13 @@ def test_route_and_rerank_stages_compose_to_two_stage_query():
     staged = stages.decode_rerank(state.store.ids, routes, sc, pos,
                                   cfg.store_depth, nprobe)
     fused = pipeline.query(cfg, state, q, k, two_stage=True, nprobe=nprobe)
-    for a, b in zip(fused, staged):
+    # rows, doc ids and clusters exact; scores to 1e-6, because the fused
+    # query and the staged stages are different programs whose fp32 dots
+    # XLA may fuse and accumulate differently (a 1-ulp difference)
+    for a, b in zip(fused[1:], staged[1:]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_allclose(np.asarray(fused[0]), np.asarray(staged[0]),
+                               rtol=1e-6, atol=1e-6)
 
 
 def test_upsert_snapshot_invariants_after_refresh():

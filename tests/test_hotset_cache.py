@@ -306,7 +306,8 @@ def test_sharded_delta_publish_invalidates_precisely_4dev():
                                     admit_prob=1.0, update_interval=10**9,
                                     store_depth=4)
         stream = make_stream("iot", dim=32)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         eng = ShardedEngine(cfg, mesh, jax.random.key(0),
                             reconcile_every=10**9, reconcile_mode="delta")
         srv = AsyncServer(cfg, ServerConfig(max_batch=8, max_wait_ms=0.0,

@@ -334,7 +334,8 @@ def test_sharded_device_counters_aggregate_across_shards():
     from repro.engine.sharded import ShardedEngine
 
     cfg = small_cfg(store_depth=4)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2), ("data", "model"))
     eng = ShardedEngine(cfg, mesh, jax.random.key(0),
                         reconcile_every=10**9, reconcile_mode="delta")
     stream = make_stream("iot", dim=DIM)

@@ -396,7 +396,8 @@ def test_sharded_quantized_store_delta_identity_and_query_parity():
                                     update_interval=48, alpha=-1.0,
                                     store_depth=4, store_dtype="int8")
         stream = make_stream("iot", dim=32)
-        mesh = jax.make_mesh((D, M), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((D, M), ("data", "model"))
         full = ShardedEngine(cfg, mesh, jax.random.key(0),
                              reconcile_every=10**9)
         delta = ShardedEngine(cfg, mesh, jax.random.key(0),

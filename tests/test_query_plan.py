@@ -323,7 +323,8 @@ def test_sharded_plan_parity_four_device():
                                         update_interval=32, alpha=-1.0,
                                         store_depth=8,
                                         store_dtype=store_dtype)
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 2), ("data", "model"))
             eng = ShardedEngine(cfg, mesh, jax.random.key(0),
                                 reconcile_every=100)
             rng = np.random.default_rng(3)

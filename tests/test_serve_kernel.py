@@ -230,7 +230,8 @@ def test_sharded_fused_serve_matches_single_device():
                                         store_dtype=store_dtype)
             cfg = dataclasses.replace(
                 cfg, clus=dataclasses.replace(cfg.clus, use_pallas=True))
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 2), ("data", "model"))
             eng = ShardedEngine(cfg, mesh, jax.random.key(0),
                                 reconcile_every=100)
             rng = np.random.default_rng(3)
