@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import index as index_lib, pipeline
 from repro.engine import stages
 from repro.store import docstore
@@ -67,27 +68,34 @@ def ingest_impl(cfg: "pipeline.PipelineConfig", state: "pipeline.PipelineState",
     # fused admission: screen + assign + quantize-on-admit in ONE device
     # program (stages.admit -> kernels.admit); the store rows arrive at
     # the ring write below already in the store dtype
-    pre, r, keep, clus, labels, sims, v, vscale = stages.admit(
-        cfg.pre, cfg.clus, cfg.store, state.pre, state.clus, x, live)
-    hh, masked_labels, hh_info = stages.count(cfg.hh, state.hh, labels, keep,
-                                              k_hh)
-    rep_ids, rep_sims = stages.update_representatives(
-        state.rep_ids, state.rep_sims, labels, sims, doc_ids, keep, k)
+    # each stage runs under a named scope, so a profiler trace names the
+    # device ops of each (the ``op_name`` of their HLO instructions)
+    with jax.named_scope("admit"):
+        pre, r, keep, clus, labels, sims, v, vscale = stages.admit(
+            cfg.pre, cfg.clus, cfg.store, state.pre, state.clus, x, live)
+    with jax.named_scope("counter_scan"):
+        hh, masked_labels, hh_info = stages.count(cfg.hh, state.hh, labels,
+                                                  keep, k_hh)
+    with jax.named_scope("representatives"):
+        rep_ids, rep_sims = stages.update_representatives(
+            state.rep_ids, state.rep_sims, labels, sims, doc_ids, keep, k)
 
     stored = keep & (hh_info["admitted"] | hh_info["hit"])
     # arrival index among live rows (== arange(B) for an unpadded batch)
     stamps = state.arrivals + jnp.cumsum(live.astype(jnp.int32)) - 1
-    store = stages.store_write(cfg.store, state.store, x, labels, stored,
-                               doc_ids, stamps, v=v, vscale=vscale)
+    with jax.named_scope("ring_write"):
+        store = stages.store_write(cfg.store, state.store, x, labels, stored,
+                                   doc_ids, stamps, v=v, vscale=vscale)
 
     since = state.since_upsert + n_live
     refresh = since >= cfg.update_interval
-    new_index, route_labels = jax.lax.cond(
-        refresh,
-        lambda args: stages.upsert_snapshot(cfg.index, args[0], hh,
-                                            clus.centroids, rep_ids),
-        lambda args: args,
-        (state.index, state.route_labels))
+    with jax.named_scope("upsert"):
+        new_index, route_labels = jax.lax.cond(
+            refresh,
+            lambda args: stages.upsert_snapshot(cfg.index, args[0], hh,
+                                                clus.centroids, rep_ids),
+            lambda args: args,
+            (state.index, state.route_labels))
 
     new_state = pipeline.PipelineState(
         pre=pre, clus=clus, hh=hh, index=new_index, store=store,
@@ -228,14 +236,20 @@ class Engine:
         """
         st = self.state
         self._version += 1
-        self._update_publish_info()
-        return ServingSnapshot(
-            index=jax.tree.map(jnp.copy, st.index),
-            route_labels=jnp.copy(st.route_labels),
-            store=jax.tree.map(jnp.copy, st.store),
-            version=self._version,
-            published_at=time.time(),
-        )
+        tr = obs.tracer()
+        with (tr.span("publish.signature", cat="ingest")
+              if tr is not None else obs.NO_SPAN):
+            sig = self._host_signature()
+        self._update_publish_info(sig)
+        with (tr.span("publish.copy", cat="ingest")
+              if tr is not None else obs.NO_SPAN):
+            return ServingSnapshot(
+                index=jax.tree.map(jnp.copy, st.index),
+                route_labels=jnp.copy(st.route_labels),
+                store=jax.tree.map(jnp.copy, st.store),
+                version=self._version,
+                published_at=time.time(),
+            )
 
     def _host_signature(self):
         """(cluster counts, ring write ptrs, rep ids) — the exact change
@@ -253,11 +267,11 @@ class Engine:
         st = self.state
         jax.block_until_ready((st.clus.counts, st.store.ptr, st.rep_ids))
 
-    def _update_publish_info(self):
-        """Diff the host signature against the previous publish to name
-        the exact dirty-cluster set this publication can have changed."""
+    def _update_publish_info(self, sig):
+        """Diff the host signature ``sig`` against the previous publish's
+        to name the exact dirty-cluster set this publication can have
+        changed."""
         k = self.cfg.clus.num_clusters
-        sig = self._host_signature()
         if self._pub_sig is None:
             self.last_publish_info = {"mode": "full", "dirty_clusters": k,
                                       "dirty_frac": 1.0, "dirty": None}

@@ -13,7 +13,13 @@ free on serving hot paths:
   * device-side pipeline counters (``engine.stages.pipeline_counters``)
     are computed in-graph and fetched as one small host transfer **per
     publish only** — never per query batch — so enabling metrics adds
-    zero device syncs to the query path.
+    zero device syncs to the query path;
+  * every span the tracer opens is mirrored, on the same thread, into a
+    ``jax.profiler.TraceAnnotation`` of the same name and args, so a
+    profiler trace holds the program's spans on the device's clock;
+    with the tracer enabled, a ``gc.collect`` span marks each full
+    collection (a hook on ``gc.callbacks``, removed by ``disable()``).
+    Disabled, no span and no annotation is built.
 
 Enable programmatically (``obs.enable()``), via the serving launcher's
 ``--metrics-json`` / ``--trace-out`` flags, or with ``REPRO_OBS=1`` in
@@ -21,6 +27,8 @@ the environment (CI runs the async serving suite this way).
 """
 from __future__ import annotations
 
+import contextlib
+import gc
 import os
 import threading
 
@@ -30,8 +38,13 @@ from repro.obs.trace import Tracer, validate_chrome_trace
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "Tracer",
     "validate_chrome_trace", "enable", "disable", "enabled", "metrics",
-    "tracer", "count_kernel_trace",
+    "tracer", "count_kernel_trace", "NO_SPAN",
 ]
+
+# The context a site enters in place of ``tracer().span(...)`` when the
+# tracer is disabled: one shared, stateless object, so the disabled path
+# builds nothing.
+NO_SPAN = contextlib.nullcontext()
 
 _lock = threading.Lock()
 _registry: Registry | None = None
@@ -50,6 +63,7 @@ def enable(metrics: bool = True, trace: bool = True,
             _registry = Registry()
         if trace and _tracer is None:
             _tracer = Tracer(max_events=max_trace_events)
+            gc.callbacks.append(_tracer.on_gc)
         return _registry, _tracer
 
 
@@ -58,6 +72,8 @@ def disable() -> None:
     no-op fast path."""
     global _registry, _tracer
     with _lock:
+        if _tracer is not None and _tracer.on_gc in gc.callbacks:
+            gc.callbacks.remove(_tracer.on_gc)
         _registry = None
         _tracer = None
 

@@ -28,13 +28,24 @@ from __future__ import annotations
 import contextlib
 import threading
 
+from repro import obs
 from repro.engine.plan import PlanSpace, QueryPlan
+
+
+def _wait_span(cls: str):
+    """A ``dispatch.wait`` span (arg ``cls``) while the section is busy,
+    or None when tracing is off."""
+    tr = obs.tracer()
+    return tr.span("dispatch.wait", cat="dispatch", cls=cls) \
+        if tr is not None else None
 
 
 class PriorityDispatcher:
     """Two-class mutual-exclusion section: query acquisitions preempt
     ingest acquisitions (only in queueing order — a holder is never
-    interrupted). Not reentrant; hold times must be dispatch-only."""
+    interrupted). Not reentrant; hold times must be dispatch-only.
+    A ``dispatch.wait`` span covers the wait of an acquisition that
+    found the section busy."""
 
     def __init__(self):
         self._cond = threading.Condition()
@@ -46,8 +57,12 @@ class PriorityDispatcher:
         """Acquire for a query-flush dispatch (high priority)."""
         with self._cond:
             self._queries_waiting += 1
-            while self._busy:
-                self._cond.wait()
+            if self._busy:
+                span = _wait_span("query")
+                while self._busy:
+                    self._cond.wait()
+                if span is not None:
+                    span.end()
             self._queries_waiting -= 1
             self._busy = True
         try:
@@ -62,8 +77,12 @@ class PriorityDispatcher:
         """Acquire for an ingest/publish dispatch (low priority): waits
         while the section is held OR any query flush is queued for it."""
         with self._cond:
-            while self._busy or self._queries_waiting:
-                self._cond.wait()
+            if self._busy or self._queries_waiting:
+                span = _wait_span("ingest")
+                while self._busy or self._queries_waiting:
+                    self._cond.wait()
+                if span is not None:
+                    span.end()
             self._busy = True
         try:
             yield

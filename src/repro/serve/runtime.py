@@ -239,10 +239,17 @@ class QueryFrontend:
         plan_args = ({} if plan is None else
                      {"plan_nprobe": plan.nprobe, "plan_depth": plan.depth,
                       "degraded": degraded, "shed": plan.shed})
-        fspan = (tr.span("flush", batch=len(batch), queue_depth=depth,
-                         **plan_args)
-                 if tr is not None else None)
+        fspan = None
         t0 = time.perf_counter()
+        if tr is not None:
+            # the age of the popped queries at pop time, from the ``t``
+            # that submit stored
+            waits = [(t0 - b["t"]) * 1e3 for b in batch]
+            fspan = tr.span("flush", batch=len(batch), queue_depth=depth,
+                            ticket_first=batch[0]["ticket"],
+                            ticket_last=batch[-1]["ticket"],
+                            wait_ms_mean=sum(waits) / len(waits),
+                            wait_ms_max=max(waits), **plan_args)
         if plan is not None and plan.shed:
             # shed: never touches the embedder or the engine — the
             # explicit overload answer, cheap by construction
@@ -260,12 +267,15 @@ class QueryFrontend:
                     q = self.embed_fn(raw)
             else:
                 q = np.stack(raw)
-            scores, rows, ids, labels = self._query_batch(
-                np.asarray(q, np.float32), plan)
+            with (tr.span("flush.dispatch") if tr is not None
+                  else obs.NO_SPAN):
+                scores, rows, ids, labels = self._query_batch(
+                    np.asarray(q, np.float32), plan)
             # one host transfer per output (a per-row np.asarray in the
             # loop below would dispatch a multi-device slice per query)
-            scores, ids, labels = (np.asarray(scores), np.asarray(ids),
-                                   np.asarray(labels))
+            with tr.span("flush.fetch") if tr is not None else obs.NO_SPAN:
+                scores, ids, labels = (np.asarray(scores), np.asarray(ids),
+                                       np.asarray(labels))
         lat = (time.perf_counter() - t0) * 1e3
         meta = self._batch_meta()
         if plan is not None:
@@ -315,21 +325,12 @@ class QueryFrontend:
                     if self._controller is not None else 0)
                 if plan.shed:
                     reg.counter("serve_shed_total").inc(len(batch))
-        if tr is not None:
+        if fspan is not None:
+            # the answered ticket range and the snapshot it was answered
+            # from correlate the flush with the ingest.publish spans
             fspan.args.update(meta if plan is None else
                               {k: v for k, v in meta.items() if k != "plan"})
             fspan.end()
-            now = tr.now_us()
-            # per-query submit->answer spans, correlated to the snapshot
-            # they were answered from (and the plan that served them)
-            # via args
-            for o in out:
-                e2e_us = o["enqueue_to_answer_ms"] * 1e3
-                tr.complete("query", now - e2e_us, e2e_us, cat="query",
-                            ticket=o["ticket"],
-                            **{k: v for k, v in o.items()
-                               if k == "snapshot_version"},
-                            **plan_args)
         return out
 
     def drain(self) -> list[dict]:
@@ -573,17 +574,18 @@ class AsyncServer(QueryFrontend):
                 item.set()
                 self._inflight = None
                 continue
-            seq, x, ids = item
+            seq, x, ids, t_put = item
             if self._inflight_stage == "admit":
                 faults.fault_point("ingest.admit", seq=seq)
                 tr = obs.tracer()
-                span = (tr.span("ingest.admit", cat="ingest", seq=seq,
-                                batch=int(np.asarray(ids).size))
-                        if tr is not None else None)
-                with self._dispatch.ingest():
-                    self.engine.ingest(x, ids)
-                if span is not None:  # dispatch time (execution is async)
-                    span.end()
+                # dispatch time (execution is async); ``wait_ms`` is the
+                # batch's time from its enqueue to here
+                with (tr.span("ingest.admit", cat="ingest", seq=seq,
+                              batch=int(np.asarray(ids).size),
+                              wait_ms=(time.perf_counter() - t_put) * 1e3)
+                      if tr is not None else obs.NO_SPAN):
+                    with self._dispatch.ingest():
+                        self.engine.ingest(x, ids)
                 self._docs_ingested += int(np.sum(np.asarray(ids) >= 0))
                 self._since_publish += 1
                 if self._durable is not None:
@@ -662,14 +664,16 @@ class AsyncServer(QueryFrontend):
         # at least everything ingested up to here
         docs = self._docs_ingested
         reg, tr = obs.metrics(), obs.tracer()
-        span = (tr.span("ingest.publish", cat="ingest")
+        span = (tr.span("ingest.publish", cat="ingest", docs=docs)
                 if tr is not None else None)
         # host-blocking publish prep (e.g. the sharded engine's dirty
         # signature waits on ingest execution) runs OUTSIDE the dispatch
         # lock so a concurrent flush never stalls behind it
         prepare = getattr(self.engine, "prepare_publish", None)
         if prepare is not None:
-            prepare()
+            with (tr.span("publish.prepare", cat="ingest")
+                  if tr is not None else obs.NO_SPAN):
+                prepare()
         t0 = time.perf_counter()
         with self._dispatch.ingest():  # publish defers to queued flushes
             snap = self.engine.publish()
@@ -765,7 +769,7 @@ class AsyncServer(QueryFrontend):
             else:
                 seq = self._next_seq
                 self._next_seq += 1
-            self._put((seq, x, ids), timeout)
+            self._put((seq, x, ids, time.perf_counter()), timeout)
         if span is not None:
             span.args["seq"] = seq
             span.end()

@@ -7,8 +7,9 @@
   enabled, the device counters are fetched at publish time ONLY — never
   from the query path (the "zero device syncs on queries" property);
 * trace export: a threaded async serving run produces a structurally
-  valid Chrome trace-event JSON whose per-query spans carry the snapshot
-  version they were answered from (correlated against actual publishes);
+  valid Chrome trace-event JSON whose flush spans carry the ticket range
+  they answered and the snapshot version they answered from (correlated
+  against actual publishes);
 * satellite fixes: per-query latency window (p90 + window sizes in
   ``latency_stats``), wall-clock snapshot age with the never-published
   guard, and stat exactness under concurrent submit/flush.
@@ -160,17 +161,35 @@ def test_tracer_chrome_export_is_valid_and_bounded():
     tr = Tracer(max_events=4)
     with tr.span("outer", cat="t", a=1) as sp:
         sp.args["b"] = 2          # mid-span correlation fill-in
-        tr.instant("mark", cat="t")
+        with tr.span("inner", cat="t"):
+            pass
     tr.counter("depth", {"q": 3})
-    tr.complete("query", 100.0, 50.0, ticket=7, snapshot_version=2)
     for _ in range(4):            # overflow the bounded buffer
-        tr.instant("spam")
+        tr.counter("spam", {"q": 1})
     assert len(tr) == 4
     obj = tr.to_chrome()
     assert validate_chrome_trace(obj) == []
     assert obj["otherData"]["dropped_events"] > 0
     names = [e["name"] for e in obj["traceEvents"]]
     assert "process_name" in names  # metadata event survives overflow
+
+
+def test_full_collections_are_traced_while_tracing_is_enabled():
+    import gc
+
+    _, tr = obs.enable(metrics=False, trace=True)
+    assert tr.on_gc in gc.callbacks
+    gc.collect(0)                 # a young collection is not traced
+    gc.collect()
+    spans = [e for e in tr.events() if e["name"] == "gc.collect"]
+    assert len(spans) >= 1
+    assert all(e["args"] == {"generation": 2} and e["dur"] >= 0.0
+               for e in spans)
+    obs.disable()
+    assert tr.on_gc not in gc.callbacks
+    gc.collect()
+    assert len([e for e in tr.events() if e["name"] == "gc.collect"]) \
+        == len(spans)
 
 
 def test_validate_chrome_trace_flags_malformed_events():
@@ -390,16 +409,22 @@ def test_async_trace_spans_correlate_with_published_versions():
     events = tr.events()
     published = {e["args"]["version"] for e in events
                  if e["name"] == "ingest.publish"}
-    queries = [e for e in events if e["name"] == "query"]
-    assert queries, "no per-query spans recorded"
-    for q in queries:
-        assert q["ph"] == "X" and q["dur"] >= 0.0
-        assert "ticket" in q["args"]
-        # every answer was served from a snapshot that was either the
-        # constructor's initial publish (v1) or traced as published
-        assert q["args"]["snapshot_version"] in published | {1}
+    assert not [e for e in events if e["name"] == "query"], \
+        "per-query events are gone: flushes name their ticket ranges"
     flushes = [e for e in events if e["name"] == "flush"]
-    assert flushes and all("snapshot_version" in f["args"] for f in flushes)
+    assert flushes
+    answered = []
+    for f in flushes:
+        assert f["ph"] == "X" and f["dur"] >= 0.0
+        a = f["args"]
+        assert a["ticket_last"] - a["ticket_first"] + 1 == a["batch"]
+        assert 0.0 <= a["wait_ms_mean"] <= a["wait_ms_max"]
+        # every flush answered from a snapshot that was either the
+        # constructor's initial publish (v1) or traced as published
+        assert a["snapshot_version"] in published | {1}
+        answered += range(a["ticket_first"], a["ticket_last"] + 1)
+    # the ticket ranges cover every answered query exactly once
+    assert sorted(answered) == list(range(len(answered)))
 
 
 def test_disabled_obs_records_nothing_and_answers_identically():
