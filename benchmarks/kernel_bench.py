@@ -1,5 +1,5 @@
 """Per-kernel microbenchmark harness + autotuner:
-prefilter / assign / admit / rerank / serve.
+prefilter / assign / admit / rerank / serve / counter.
 
 Reports per-call wall-clock (median of interleaved rounds) and docs- or
 queries-per-second for both the dispatching paths of each kernel — the
@@ -13,7 +13,14 @@ can quote before/after numbers without running the full paper tables:
 
 Shapes default to the paper configuration (microbatch 50, dim 384,
 k=100 clusters, n=5 basis vectors, query batch 50, ring depth 16,
-nprobe 8). Output is one CSV row per (kernel, path):
+nprobe 8). The ``counter`` case times the heavy-hitter ``update_batch``
+alone, its per-arrival scan (``scan``) against its event loop
+(``events``), in three fixed shapes: a 2,048-arrival batch into a full
+4,218-slot counter that every arrival hits (the paper's 150 MB
+deployment once its counter has filled), the same batch of 2,048 novel
+labels into an empty one (2,048 inserts), and the query hot set's worst
+case, 32 novel signatures into a full 32-slot counter at u = 1 (32
+evictions). Output is one CSV row per (kernel, path):
 ``kernel,path,us_per_call,items_per_s,modeled_hbm_bytes,modeled_flops``.
 The fused ``serve`` rows additionally report the kernel's analytic DMA
 ledger (``serve_dma_bytes``) against the roofline ideal of one pass over
@@ -85,6 +92,41 @@ def _serve_problem(args):
         embs = jnp.asarray(rng.normal(size=(K, depth, d)), jnp.float32)
         scales = None
     return qr, qr, vectors, valid, labels, embs, live, scales
+
+
+def _counter_cases() -> dict:
+    """``counter`` paths: (scan | events) x the three shapes above, each a
+    zero-arg call over device-resident inputs (same state every call)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import heavy_hitter as hh
+
+    rng = np.random.default_rng(0)
+    full = hh.HHConfig(capacity=4218, admit_prob=0.05)
+    hot = hh.HHConfig(capacity=32, admit_prob=1.0)
+    full_state = hh.init(full)._replace(
+        labels=jnp.asarray(rng.permutation(4218), jnp.int32),
+        counts=jnp.asarray(rng.integers(1, 1000, 4218), jnp.int32))
+    hot_state = hh.init(hot)._replace(
+        labels=jnp.arange(32, dtype=jnp.int32),
+        counts=jnp.asarray(rng.integers(1, 50, 32), jnp.int32))
+    shapes = {
+        "full2048x4218": (full, full_state,
+                          rng.integers(0, 4218, 2048)),
+        "fill2048x4218": (full, hh.init(full), rng.permutation(4218)[:2048]),
+        "hotset32x32": (hot, hot_state, 1000 + np.arange(32)),
+    }
+    key = jax.random.key(0)
+    paths = {}
+    for shape, (cfg, state, labels) in shapes.items():
+        labels = jnp.asarray(labels, jnp.int32)
+        for path, fn in (("scan", hh.update_batch_scan),
+                         ("events", hh.update_batch_events)):
+            run = jax.jit(functools.partial(fn, cfg))
+            paths[f"{path}.{shape}"] = (labels.shape[0], functools.partial(
+                run, state, labels, key))
+    return paths
 
 
 def _cases(args):
@@ -175,10 +217,14 @@ def run(args) -> list[dict]:
 
     rows = []
     cases = _cases(args)
-    names = args.kernel or list(cases)
+    names = args.kernel or [*cases, "counter"]
     for name in names:
-        items, paths = cases[name]
-        for path, fn in paths.items():
+        if name == "counter":
+            paths = _counter_cases()
+        else:
+            items, paths = cases[name]
+            paths = {path: (items, fn) for path, fn in paths.items()}
+        for path, (items, fn) in paths.items():
             sec = _bench(fn, reps=args.reps, rounds=args.rounds)
             # modeled HBM traffic from the compiled HLO (the roofline
             # substitute for a hardware profiler); lands in the metrics
@@ -266,7 +312,7 @@ def main() -> None:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--kernel", action="append",
                    choices=["prefilter", "assign", "admit", "rerank",
-                            "serve"],
+                            "serve", "counter"],
                    help="kernel(s) to bench; default all")
     p.add_argument("--B", type=int, default=50, help="microbatch (paper: 50)")
     p.add_argument("--d", type=int, default=384)

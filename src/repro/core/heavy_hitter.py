@@ -20,9 +20,16 @@ Adaptive u_t / B_t (paper Table 9): when the rate of novel labels inside a
 window exceeds ``novel_hi``, grow the admission probability and the active
 capacity; decay them back toward defaults when the stream stabilizes.
 
-Per-arrival semantics are preserved exactly: a microbatch is a ``lax.scan``
-over items. All state transitions are pure — checkpointable and mergeable
-across data shards (see distributed/collectives.py).
+Per-arrival semantics are preserved exactly. The reference form of a
+microbatch is ``update_batch_scan``, a ``lax.scan`` of ``update_one`` over
+items. ``update_batch`` runs it for RANDOM_EVICT, COUNT_MIN, Morris and
+adaptive counters. For exact, fixed MIN_EVICT and SPACE_SAVING counters it
+runs ``update_batch_events`` instead, which gives bit-identical state and
+per-arrival info: only the arrivals that change the counter's labels (an
+insert or an eviction) are handled one at a time, and the hits and rejected
+misses between two of them are applied as one order-free vector step. All
+state transitions are pure — checkpointable and mergeable across data
+shards (see distributed/collectives.py).
 """
 from __future__ import annotations
 
@@ -286,12 +293,12 @@ def update_one(
     return new_state, info
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def update_batch(
+def update_batch_scan(
     cfg: HHConfig, state: HHState, labels: jnp.ndarray, key: jax.Array
 ) -> tuple[HHState, dict]:
     """Scan the per-arrival update over a microbatch (paper semantics exact).
 
+    The reference form of ``update_batch``, for every configuration.
     labels: [B] int32 cluster labels, −1 for upstream-dropped items.
     """
     keys = jax.random.split(key, labels.shape[0])
@@ -301,6 +308,137 @@ def update_batch(
         return update_one(cfg, s, lbl, k)
 
     return jax.lax.scan(step, state, (labels, keys))
+
+
+def events_exact(cfg: HHConfig) -> bool:
+    """True where ``update_batch_events`` reproduces the scan: exact counts,
+    a fixed u and capacity, and a victim that is the least-count slot.
+    RANDOM_EVICT and COUNT_MIN draw or hash per arrival, Morris increments
+    are random and adaptive counters change u and B inside the batch."""
+    return (not cfg.morris and not cfg.adaptive
+            and cfg.policy in (Policy.MIN_EVICT, Policy.SPACE_SAVING))
+
+
+def update_batch_events(
+    cfg: HHConfig, state: HHState, labels: jnp.ndarray, key: jax.Array
+) -> tuple[HHState, dict]:
+    """``update_batch_scan`` for ``events_exact`` configurations, looping
+    over label-changing arrivals ("events") instead of over every arrival.
+
+    Between two events the slot that holds each label is fixed, so every
+    arrival there is either a hit (``counts[slot] += 1``, which commutes) or
+    a rejected miss (no change). Each ``while_loop`` step finds the first
+    event ``e`` at or after a position ``p``, records the hits of
+    ``[p, e)``, runs event ``e`` exactly as ``update_one`` would and goes on
+    from ``e + 1``. While the counter has room an event is an insert at the
+    first empty slot, which reads no count, so the hits wait and are added
+    in one scatter when the counter fills or the batch ends; once it is
+    full an event evicts the least-count slot, so each step first adds the
+    hits before it. The admission draws are the scan's own: the same
+    per-arrival keys, drawn up front. Live labels are distinct (as every
+    update keeps them), so an eviction unmaps only its victim slot.
+    """
+    n = labels.shape[0]
+    bmax = state.labels.shape[0]
+    cap = state.active_capacity
+    slot_ids = jnp.arange(bmax, dtype=jnp.int32)
+    pos = jnp.arange(n, dtype=jnp.int32)
+
+    keys = jax.random.split(key, n)
+    u = jax.vmap(lambda k: jax.random.uniform(jax.random.split(k, 3)[0]))(keys)
+    gate = u <= state.admit_prob
+    valid = labels >= 0
+    admit_room = gate if cfg.gate_below_capacity else valid
+    admit_full = gate if cfg.policy == Policy.MIN_EVICT else valid
+
+    # the slot holding each arrival's label in the incoming state, or -1
+    occ = active_mask(state)
+    held = jnp.min(jnp.where(occ[None, :] & (state.labels[None, :]
+                                             == labels[:, None]),
+                             slot_ids[None, :], bmax), axis=1)
+    mslot = jnp.where(held < bmax, held, -1).astype(jnp.int32)
+
+    def add_hits(cnt, hit, slot):
+        return cnt.at[jnp.where(hit, slot, bmax)].add(1, mode="drop")
+
+    def step(full: bool):
+        def body(c):
+            p, size, lab, cnt, mslot, slot_out, adm_out, ev_out, n_evict = c
+            event = ((pos >= p) & valid & (mslot < 0)
+                     & (admit_full if full else admit_room))
+            e = jnp.min(jnp.where(event, pos, n))
+            hit = (pos >= p) & (pos < e) & (mslot >= 0)
+            slot_out = jnp.where(hit, mslot, slot_out)
+            ev = e < n                 # event e; nothing when e == n
+            if full:
+                cnt = add_hits(cnt, hit, mslot)
+                counts_f = jnp.where((lab != EMPTY) & (slot_ids < cap), cnt,
+                                     INT_MAX)
+                slot = jnp.argmin(counts_f).astype(jnp.int32)
+                new_count = (jnp.int32(1) if cfg.policy == Policy.MIN_EVICT
+                             else counts_f[slot] + 1)
+                evicted = jnp.where(ev, lab[slot], EMPTY)
+            else:
+                slot = jnp.argmax((lab == EMPTY) & (slot_ids < cap)
+                                  ).astype(jnp.int32)
+                new_count = jnp.int32(1)
+                evicted = EMPTY
+            lbl = labels[jnp.minimum(e, n - 1)]
+            lab = jnp.where(ev, lab.at[slot].set(lbl), lab)
+            cnt = jnp.where(ev, cnt.at[slot].set(new_count), cnt)
+            if full:                   # the victim's arrivals now miss
+                mslot = jnp.where(ev & (mslot == slot), -1, mslot)
+                n_evict = n_evict + ev.astype(jnp.int32)
+            else:
+                size = size + ev.astype(jnp.int32)
+            mslot = jnp.where(ev & (labels == lbl), slot, mslot)
+            at_e = ev & (pos == e)
+            return (e + 1, size, lab, cnt, mslot,
+                    jnp.where(at_e, slot, slot_out), adm_out | at_e,
+                    jnp.where(at_e, evicted, ev_out), n_evict)
+        return body
+
+    c = (jnp.int32(0), jnp.sum(occ.astype(jnp.int32)), state.labels,
+         state.counts, mslot, jnp.full((n,), -1, jnp.int32),
+         jnp.zeros((n,), bool), jnp.full((n,), EMPTY, jnp.int32),
+         jnp.int32(0))
+    c = jax.lax.while_loop(lambda c: (c[0] < n) & (c[1] < cap), step(False), c)
+    p, size, lab, cnt, mslot, slot_out, adm_out, ev_out, n_evict = c
+    cnt = add_hits(cnt, (slot_out >= 0) & ~adm_out, slot_out)
+    c = (p, size, lab, cnt, mslot, slot_out, adm_out, ev_out, n_evict)
+    c = jax.lax.while_loop(lambda c: c[0] < n, step(True), c)
+    _, _, lab, cnt, _, slot_out, adm_out, ev_out, n_evict = c
+
+    write = slot_out >= 0
+    hit = write & ~adm_out
+    n_valid = jnp.sum(valid.astype(jnp.int32))
+    n_novel = n_valid - jnp.sum(hit.astype(jnp.int32))
+    new_state = state._replace(
+        labels=lab,
+        counts=cnt,
+        novel_in_window=state.novel_in_window + n_novel,
+        seen_in_window=state.seen_in_window + n_valid,
+        total_seen=state.total_seen + n_valid,
+        total_evictions=state.total_evictions + n_evict,
+        total_writes=state.total_writes + jnp.sum(write.astype(jnp.int32)),
+    )
+    info = {"admitted": adm_out, "hit": hit, "evicted_label": ev_out,
+            "slot": slot_out}
+    return new_state, info
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def update_batch(
+    cfg: HHConfig, state: HHState, labels: jnp.ndarray, key: jax.Array
+) -> tuple[HHState, dict]:
+    """One microbatch of arrivals, with the per-arrival semantics of
+    ``update_batch_scan``; ``update_batch_events`` where ``events_exact``.
+
+    labels: [B] int32 cluster labels, −1 for upstream-dropped items.
+    """
+    if events_exact(cfg):
+        return update_batch_events(cfg, state, labels, key)
+    return update_batch_scan(cfg, state, labels, key)
 
 
 def merge(cfg: HHConfig, a: HHState, b: HHState) -> HHState:

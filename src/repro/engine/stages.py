@@ -108,7 +108,9 @@ def admit(pre_cfg: prefilter.PrefilterConfig,
 
 def count(hh_cfg: heavy_hitter.HHConfig, hh_state, labels: jnp.ndarray,
           keep: jnp.ndarray, key: jax.Array):
-    """(4) heavy-hitter counting over retained labels (per-arrival scan)."""
+    """(4) heavy-hitter counting over retained labels (per-arrival
+    semantics; ``heavy_hitter.update_batch`` picks the scan or the event
+    loop from ``hh_cfg``)."""
     masked_labels = jnp.where(keep, labels, -1).astype(jnp.int32)
     hh, hh_info = heavy_hitter.update_batch(hh_cfg, hh_state, masked_labels, key)
     return hh, masked_labels, hh_info
@@ -282,8 +284,8 @@ def decode_pipeline_counters(stacked) -> dict:
     single-device engine): aggregate across shards per
     ``PIPELINE_COUNTER_COMBINE`` and derive the rates the paper's
     operational claims are stated in (admission accept rate, ring
-    occupancy, counter saturation). Pure numpy — runs on the host after
-    the one publish-time transfer."""
+    occupancy, counter saturation, label-change rate). Pure numpy — runs
+    on the host after the one publish-time transfer."""
     import numpy as np
 
     arr = np.asarray(stacked, dtype=np.int64)
@@ -298,6 +300,11 @@ def decode_pipeline_counters(stacked) -> dict:
     out["admit_rate"] = out["admitted"] / max(out["arrivals"], 1)
     out["store_fill"] = out["store_live"] / max(out["store_slots"], 1)
     out["hh_occupancy"] = out["hh_occupied"] / max(out["hh_capacity"], 1)
+    # arrivals that changed the counter's labels: evictions, plus inserts,
+    # which equal the occupied slots where no slot is ever emptied (the
+    # counters that heavy_hitter.update_batch runs as an event loop)
+    out["hh_label_change_rate"] = ((out["hh_evictions"] + out["hh_occupied"])
+                                   / max(out["hh_seen"], 1))
     return out
 
 
