@@ -1,5 +1,5 @@
 """Per-kernel microbenchmark harness + autotuner:
-prefilter / assign / admit / rerank / serve / counter.
+prefilter / assign / admit / rerank / serve / counter / fetch.
 
 Reports per-call wall-clock (median of interleaved rounds) and docs- or
 queries-per-second for both the dispatching paths of each kernel — the
@@ -20,7 +20,15 @@ alone, its per-arrival scan (``scan``) against its event loop
 deployment once its counter has filled), the same batch of 2,048 novel
 labels into an empty one (2,048 inserts), and the query hot set's worst
 case, 32 novel signatures into a full 32-slot counter at u = 1 (32
-evictions). Output is one CSV row per (kernel, path):
+evictions). The ``fetch`` case times the serving flush's answer fetch
+alone: one batch's answers (float32 scores, int32 ids and labels,
+``[Q, topk]``, made fresh on the device for every fetch, which is timed
+from a finished program to the host arrays) for Q in 1, 16 and 32,
+fetched three ways: three sequential ``np.asarray`` calls (``serial``),
+three ``copy_to_host_async`` issued at once then read (``async``, the
+runtime's ``serve.runtime.fetch_answers``), and a pack into one int32
+buffer (float32 by bit pattern) plus one transfer (``packed``). Output is
+one CSV row per (kernel, path):
 ``kernel,path,us_per_call,items_per_s,modeled_hbm_bytes,modeled_flops``.
 The fused ``serve`` rows additionally report the kernel's analytic DMA
 ledger (``serve_dma_bytes``) against the roofline ideal of one pass over
@@ -129,6 +137,58 @@ def _counter_cases() -> dict:
     return paths
 
 
+FETCH_QUERIES = (1, 16, 32)
+
+
+def _fetch_rows(args) -> list[dict]:
+    """``fetch`` rows: median microseconds per fetch of one batch's
+    answers, the three ways interleaved within every repetition."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from repro.serve.runtime import fetch_answers
+
+    def serial(out):
+        return tuple(np.asarray(a) for a in out)
+
+    pack = jax.jit(lambda out: jnp.stack(
+        [lax.bitcast_convert_type(a, jnp.int32) for a in out]))
+
+    def packed(out):
+        host = np.asarray(pack(out))
+        return tuple(h.view(a.dtype) for h, a in zip(host, out))
+
+    paths = {"serial": serial, "async": lambda out: fetch_answers(out)[0],
+             "packed": packed}
+    rows = []
+    for Q in FETCH_QUERIES:
+        shape = (Q, args.topk)
+
+        @jax.jit
+        def answers(i):
+            ids = jnp.arange(Q * args.topk, dtype=jnp.int32).reshape(shape)
+            return (jnp.where(ids % 7 == 0, -jnp.inf, ids * 0.5 + i),
+                    ids + i, ids % 16)
+
+        times = {name: [] for name in paths}
+        for rep in range(args.reps * args.rounds + 1):
+            for name, fetch in paths.items():
+                out = jax.block_until_ready(answers(jnp.float32(rep)))
+                t0 = time.perf_counter()
+                fetch(out)
+                if rep:   # the first repetition compiles
+                    times[name].append(time.perf_counter() - t0)
+        for name, ts in times.items():
+            sec = float(np.median(ts))
+            rows.append({"kernel": "fetch", "path": f"{name}.q{Q}",
+                         "us_per_call": round(1e6 * sec, 1),
+                         "items_per_s": round(Q / sec, 1),
+                         "modeled_hbm_bytes": 3 * Q * args.topk * 4,
+                         "modeled_flops": 0})
+    return rows
+
+
 def _cases(args):
     import jax
     import jax.numpy as jnp
@@ -217,8 +277,11 @@ def run(args) -> list[dict]:
 
     rows = []
     cases = _cases(args)
-    names = args.kernel or [*cases, "counter"]
+    names = args.kernel or [*cases, "counter", "fetch"]
     for name in names:
+        if name == "fetch":
+            rows += _fetch_rows(args)
+            continue
         if name == "counter":
             paths = _counter_cases()
         else:
@@ -312,7 +375,7 @@ def main() -> None:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--kernel", action="append",
                    choices=["prefilter", "assign", "admit", "rerank",
-                            "serve", "counter"],
+                            "serve", "counter", "fetch"],
                    help="kernel(s) to bench; default all")
     p.add_argument("--B", type=int, default=50, help="microbatch (paper: 50)")
     p.add_argument("--d", type=int, default=384)

@@ -123,6 +123,28 @@ def _route_batch(index_cfg, index, route_labels, q, nprobe):
     return stages.route(index_cfg, index, route_labels, q, nprobe)
 
 
+def fetch_answers(arrays):
+    """Host copies of a batch's answer arrays, and the number of
+    device-to-host transfers that made them.
+
+    Every transfer has a fixed cost far above the bytes of a top-k
+    answer. Waited on one after another, a batch pays it once per array;
+    so the transfers of all device arrays (``jax.Array``) are issued at
+    once and only then read, and they overlap. The arrays come back as
+    they are, dtypes included; host arrays, such as shed or cached
+    answers, pass through untouched."""
+    device = [a for a in arrays if isinstance(a, jax.Array)]
+    for a in device:
+        a.copy_to_host_async()
+    return tuple(np.asarray(a) for a in arrays), len(device)
+
+
+class _Transfers(threading.local):
+    """Device-to-host transfers made by the flush running on this
+    thread (a flush runs on one thread; several may run at once)."""
+    n = 0
+
+
 class QueryFrontend:
     """Micro-batching query front end shared by the sync and async servers.
 
@@ -175,6 +197,7 @@ class QueryFrontend:
         self._next_ticket = 0
         self._lat_sum = 0.0
         self._last_snapshot = None
+        self._transfers = _Transfers()
         self.stats = {
             "queries": 0, "docs": 0, "batches": 0, "shed": 0,
             "query_latency_ms":
@@ -267,15 +290,19 @@ class QueryFrontend:
                     q = self.embed_fn(raw)
             else:
                 q = np.stack(raw)
+            self._transfers.n = 0
             with (tr.span("flush.dispatch") if tr is not None
                   else obs.NO_SPAN):
                 scores, rows, ids, labels = self._query_batch(
                     np.asarray(q, np.float32), plan)
-            # one host transfer per output (a per-row np.asarray in the
-            # loop below would dispatch a multi-device slice per query)
-            with tr.span("flush.fetch") if tr is not None else obs.NO_SPAN:
-                scores, ids, labels = (np.asarray(scores), np.asarray(ids),
-                                       np.asarray(labels))
+            # the three answers' transfers overlap (a per-row np.asarray
+            # in the loop below would dispatch a multi-device slice per
+            # query); ``rows`` is never fetched
+            with (tr.span("flush.fetch") if tr is not None
+                  else obs.NO_SPAN) as fetch:
+                scores, ids, labels = self._fetch((scores, ids, labels))
+                if fetch is not None:
+                    fetch.args["transfers"] = self._transfers.n
         lat = (time.perf_counter() - t0) * 1e3
         meta = self._batch_meta()
         if plan is not None:
@@ -413,6 +440,13 @@ class QueryFrontend:
             out["hot_served"] = h["hot_served"]
             out["tier_rebuilds"] = h["rebuilds"]
         return out
+
+    def _fetch(self, arrays) -> tuple:
+        """Host copies of answer arrays (:func:`fetch_answers`), counted
+        against the flush running on this thread."""
+        host, n = fetch_answers(arrays)
+        self._transfers.n += n
+        return host
 
     # ------------------------------------------------------------- interface
     def _query_batch(self, q: np.ndarray, plan=None):
@@ -910,12 +944,12 @@ class AsyncServer(QueryFrontend):
                         nprobe_eff, depth_eff, self.cfg.clus.use_pallas)
             if out_c is not None:
                 n = cold_sel.size
-                sc, rw, di, cl = (np.asarray(a)[:n] for a in out_c)
+                sc, rw, di, cl = (a[:n] for a in self._fetch(out_c))
                 scores[cold_sel], rows[cold_sel] = sc, rw
                 ids[cold_sel], labels[cold_sel] = di, cl
             if out_h is not None:
                 n = hot_sel.size
-                sc, rw_t, di, cl_t = (np.asarray(a)[:n] for a in out_h)
+                sc, rw_t, di, cl_t = (a[:n] for a in self._fetch(out_h))
                 rw, cl = hotset.remap(rw_t, cl_t)
                 scores[hot_sel], rows[hot_sel] = sc, rw
                 ids[hot_sel], labels[hot_sel] = di, cl
