@@ -28,7 +28,7 @@ def _tables():
                             table10_11_pca_sensitivity,
                             table12_component_ablation, table13_downstream,
                             table14_two_stage, table15_sharded,
-                            table16_async_serving, table17_quantized_store,
+                            table17_quantized_store,
                             table18_ingest_throughput, table19_serve_fusion,
                             table20_overload, table21_hotset_cache,
                             table22_recovery)
@@ -50,7 +50,6 @@ def _tables():
         ("table13", lambda: table13_downstream.run(n_batches=n(40))),
         ("table14", lambda: table14_two_stage.run(n_batches=n(40))),
         ("table15", lambda: table15_sharded.run(n_batches=n(24))),
-        ("table16", lambda: table16_async_serving.run(n_batches=n(24))),
         ("table17", lambda: table17_quantized_store.run(n_batches=n(24))),
         ("table18", lambda: table18_ingest_throughput.run(n_batches=n(24))),
         ("table19", lambda: table19_serve_fusion.run(reps=n(40))),

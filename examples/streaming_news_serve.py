@@ -12,7 +12,7 @@ from repro.configs.streaming_rag import paper_pipeline_config
 from repro.core import baselines as B
 from repro.data.qa import FactStream, exact_match
 from repro.data.streams import make_stream
-from repro.serve.server import RAGServer, ServerConfig
+from repro.serve.runtime import AsyncServer, ServerConfig
 
 DIM = 64
 
@@ -23,9 +23,9 @@ cfg = paper_pipeline_config(dim=DIM, k=150, capacity=100,
                             update_interval=128, alpha=0.1, store_depth=8)
 # two_stage=True answers from the per-cluster document store (routed
 # exact rerank) instead of one representative doc per prototype
-server = RAGServer(cfg, ServerConfig(max_batch=16, topk=10, two_stage=True,
-                                     nprobe=10),
-                   jax.random.key(0), warmup=warm["embedding"])
+server = AsyncServer(cfg, ServerConfig(max_batch=16, topk=10,
+                                       two_stage=True, nprobe=10),
+                     jax.random.key(0), warmup=warm["embedding"])
 server.ingest(warm["embedding"], warm["doc_id"])
 
 # a static snapshot frozen after the warmup, for contrast
@@ -35,10 +35,12 @@ static_state = static.ingest(static_state,
                              jax.numpy.asarray(warm["embedding"]),
                              jax.numpy.asarray(warm["doc_id"]))
 
-# live phase: facts keep changing while we serve
+# live phase: facts keep changing while we serve; sync() returns once
+# every batch so far is ingested and published
 for _ in range(30):
     b = fact_stream.next_batch(128)
     server.serve_round(b)
+server.sync()
 
 queries = fact_stream.qa_queries(24)
 em_live, em_static = [], []
@@ -52,6 +54,7 @@ for q in queries:
     pred_s = fact_stream.read(q, np.asarray(out[2]))
     em_static.append(exact_match(pred_s, q["answer"]))
 
+server.close()
 lat = server.latency_stats()
 print(f"docs ingested           : {server.stats['docs']}")
 print(f"time-sensitive QA (EM)  : streaming={np.mean(em_live):.2f}  "

@@ -175,10 +175,11 @@ def query(cfg: PipelineConfig, state: PipelineState, q: jnp.ndarray,
     ring). (nprobe, depth) are jit-static — pass bucketed plans
     (``engine.plan.PlanSpace``) to bound the compiled-variant count.
     """
-    from repro.engine.engine import query_impl
+    from repro.engine.engine import snapshot_query_impl
 
-    return query_impl(cfg, state, q, k, two_stage=two_stage, nprobe=nprobe,
-                      depth=depth)
+    return snapshot_query_impl(cfg, state.index, state.route_labels,
+                               state.store, q, k, two_stage=two_stage,
+                               nprobe=nprobe, depth=depth)
 
 
 def state_memory_bytes(cfg: PipelineConfig) -> int:

@@ -1,16 +1,18 @@
 """Single-device composition of the engine stages.
 
-``ingest_impl``/``query_impl`` are the un-jitted stage compositions —
-``core.pipeline`` exposes them behind its original jit-compiled public API
-(``ingest_batch``/``query``), and ``engine.sharded`` calls the very same
-functions inside ``shard_map``, so single- and multi-device execution
-share one implementation and single-device behavior is bit-identical to
-the pre-engine pipeline.
+``ingest_impl`` is the un-jitted ingest composition — ``core.pipeline``
+exposes it behind its jit-compiled public API (``ingest_batch``), and
+``engine.sharded`` calls the very same function inside ``shard_map``, so
+single- and multi-device ingest share one implementation.
+``snapshot_query_impl`` is the one single-device query composition: the
+served path runs it over a published snapshot's leaves, ``pipeline.query``
+over live state, and ``ShardedEngine`` over a snapshot on a mesh with no
+model axis.
 
 ``Engine`` wraps (cfg, state) behind the small serving protocol
-(``ingest``/``query``/``index_size``) that ``serve.server.RAGServer`` is
-built on; ``sharded.ShardedEngine`` implements the same protocol over a
-device mesh.
+(``ingest``/``query``/``publish``/``query_snapshot``/``index_size``) that
+``serve.runtime.AsyncServer`` is built on; ``sharded.ShardedEngine``
+implements the same protocol over a device mesh.
 """
 from __future__ import annotations
 
@@ -120,43 +122,20 @@ def ingest_impl(cfg: "pipeline.PipelineConfig", state: "pipeline.PipelineState",
     return new_state, info
 
 
-def query_impl(cfg: "pipeline.PipelineConfig", state: "pipeline.PipelineState",
-               q: jnp.ndarray, k: int, *, two_stage: bool, nprobe: int,
-               depth: int | None = None):
-    """Retrieve top-k: (scores [Q,k], rows [Q,k], doc_ids [Q,k], clusters).
-
-    ``depth`` is a QueryPlan's rerank depth (ring slots read per routed
-    cluster); None or >= store_depth is full effort and runs the exact
-    pre-plan program. Callers pass *bucketed* plans (``engine.plan``) —
-    each distinct (nprobe, depth) is one compiled variant."""
-    from repro.core import index as index_lib
-
-    if not two_stage:
-        scores, rows, ids = index_lib.search(cfg.index, state.index, q, k)
-        return scores, rows, ids, state.route_labels[rows]
-
-    store_depth = cfg.store_depth
-    depth_eff = store_depth if depth is None else min(depth, store_depth)
-    assert store_depth > 0, "two_stage requires store_depth > 0"
-    assert k <= nprobe * depth_eff, "k must be <= nprobe * plan depth"
-    # the ONE two-stage query implementation: fused route + gather +
-    # dequant-rerank + top-k (staged route -> rerank when use_pallas=False)
-    scores, pos, routes = stages.serve_topk(
-        cfg.index, state.index, state.route_labels, state.store, q, k,
-        nprobe, cfg.clus.use_pallas, depth=depth_eff)
-    return stages.decode_rerank(state.store.ids, routes, scores, pos,
-                                depth_eff, nprobe, store_depth=store_depth)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "k", "two_stage", "nprobe",
                                     "depth"))
 def snapshot_query_impl(cfg: "pipeline.PipelineConfig", index, route_labels,
                         store, q: jnp.ndarray, k: int, *, two_stage: bool,
                         nprobe: int, depth: int | None = None):
-    """``query_impl`` over a published ServingSnapshot's leaves (the same
-    stage composition, reading snapshot state instead of live state).
-    ``depth`` is the (bucketed) QueryPlan rerank depth; None = full."""
+    """Retrieve top-k from (index, route_labels, store) — a published
+    ServingSnapshot's leaves, or live state's: (scores [Q,k], rows [Q,k],
+    doc_ids [Q,k], clusters [Q,k]).
+
+    ``depth`` is a QueryPlan's rerank depth (ring slots read per routed
+    cluster); None or >= store_depth is full effort. Callers pass
+    *bucketed* plans (``engine.plan``) — each distinct (nprobe, depth) is
+    one compiled variant."""
     if not two_stage:
         scores, rows, ids = index_lib.search(cfg.index, index, q, k)
         return scores, rows, ids, route_labels[rows]
@@ -164,6 +143,8 @@ def snapshot_query_impl(cfg: "pipeline.PipelineConfig", index, route_labels,
     depth_eff = store_depth if depth is None else min(depth, store_depth)
     assert store_depth > 0, "two_stage requires store_depth > 0"
     assert k <= nprobe * depth_eff, "k must be <= nprobe * plan depth"
+    # the ONE two-stage query implementation: fused route + gather +
+    # dequant-rerank + top-k (staged route -> rerank when use_pallas=False)
     scores, pos, routes = stages.serve_topk(
         cfg.index, index, route_labels, store, q, k, nprobe,
         cfg.clus.use_pallas, depth=depth_eff)

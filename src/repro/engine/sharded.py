@@ -67,7 +67,8 @@ from repro.distributed import sharding as shard_rules
 from repro.distributed.collectives import (distributed_rerank_topk,
                                            distributed_serve_topk)
 from repro.engine import stages
-from repro.engine.engine import ServingSnapshot, ingest_impl
+from repro.engine.engine import (ServingSnapshot, _resolve_plan,
+                                 ingest_impl, snapshot_query_impl)
 from repro.kernels.common import l2_normalize
 from repro.store import docstore
 
@@ -139,10 +140,11 @@ class ShardedEngine:
     """Data-sharded streaming ingest + cluster-sharded serving over a mesh.
 
     Implements the same serving protocol as ``engine.Engine`` —
-    ``ingest`` / ``query`` / ``index_size`` — so ``RAGServer`` can hold
-    either. ``mesh`` may carry a ``data`` axis (ingest sharding), a
-    ``model`` axis (doc-store cluster sharding), or both; a missing axis
-    degrades to that dimension running unsharded.
+    ``ingest`` / ``query`` / ``publish`` / ``query_snapshot`` /
+    ``index_size`` — so ``AsyncServer`` can hold either. ``mesh`` may
+    carry a ``data`` axis (ingest sharding), a ``model`` axis (doc-store
+    cluster sharding), or both; a missing axis degrades to that dimension
+    running unsharded.
     """
 
     def __init__(self, cfg: pipeline.PipelineConfig, mesh, key: jax.Array,
@@ -590,8 +592,6 @@ class ShardedEngine:
         depth) for this call on both paths; shards all apply the same
         ring-prefix clip, so plan queries stay parity with single-device.
         """
-        from repro.engine.engine import _resolve_plan
-
         q = jnp.asarray(q, jnp.float32)
         cfg = self.cfg
         if not two_stage:
@@ -625,12 +625,9 @@ class ShardedEngine:
                                         nprobe, doc_ids=doc_ids,
                                         store_depth=store_depth)
         if self.model_axis is None:
-            scores, pos, routes = stages.serve_topk(
-                cfg.index, snap.index, snap.route_labels, snap.store, q, k,
-                nprobe, cfg.clus.use_pallas, depth=depth_eff)
-            return stages.decode_rerank(snap.store.ids, routes, scores, pos,
-                                        depth_eff, nprobe,
-                                        store_depth=store_depth)
+            return snapshot_query_impl(
+                cfg, snap.index, snap.route_labels, snap.store, q, k,
+                two_stage=True, nprobe=nprobe, depth=depth)
         qn = l2_normalize(q)
         qr = qn if cfg.index.normalize else q
         key = (k, nprobe, depth_eff)

@@ -1,7 +1,7 @@
 """Serving launcher: ``python -m repro.launch.serve [--stream nyt] [...]``.
 
 Stands up a RAG server over a simulated stream and drives a Zipf query
-workload against the live index, printing latency/recall stats.
+workload against it, printing latency/recall stats.
 
 ``--dim`` defaults to the paper's embedding width (384, SBERT/MiniLM);
 pass a smaller one for a quick CPU run.
@@ -14,14 +14,14 @@ built over the real devices; only a run held to the CPU
 ``--xla_force_host_platform_device_count`` (which is why the mesh flag is
 parsed before jax initializes).
 
-``--async`` serves through ``serve.runtime.AsyncServer``: a background
-thread ingests the stream and publishes snapshots every
-``--reconcile-every`` batches (delta publication when sharded), so
-queries answer from the latest snapshot without waiting for ingest.
-Shutdown drains the pending queue completely — the launcher asserts
-``queries answered == queries submitted``.
+It serves through ``serve.runtime.AsyncServer``: a background thread
+ingests the stream and publishes snapshots every ``--reconcile-every``
+batches (delta publication when sharded), so queries answer from the
+latest snapshot without waiting for ingest. Shutdown drains the pending
+queue completely — the launcher asserts ``queries answered == queries
+submitted``.
 
-``--cache-entries N`` / ``--hotset`` (with ``--two-stage --async``) arm
+``--cache-entries N`` / ``--hotset`` (with ``--two-stage``) arm
 the two-level hot-set serving cache: a snapshot-versioned exact result
 cache with precise delta invalidation, and a query-side heavy-hitter hot
 set whose routed clusters pin into a compact fast tier (bounded by
@@ -29,7 +29,7 @@ set whose routed clusters pin into a compact fast tier (bounded by
 levels are bit-identical to uncached serving whenever they answer; the
 periodic report and the final summary carry hit-rate/pin numbers.
 
-``--checkpoint-dir DIR`` (with ``--async``) arms crash-safe streaming:
+``--checkpoint-dir DIR`` arms crash-safe streaming:
 every ingest batch is journaled (write-ahead, fsync'd) before it is
 enqueued, and the engine state is checkpointed every
 ``--checkpoint-every`` applied batches (full once, dirty-cluster deltas
@@ -84,9 +84,6 @@ def main():
     ap.add_argument("--mesh", default="",
                     help="'D,M' sharded engine: D data shards, M store "
                          "shards (default: single device)")
-    ap.add_argument("--async", dest="async_serve", action="store_true",
-                    help="background ingest thread + snapshot publication "
-                         "(queries never block on ingest)")
     ap.add_argument("--adaptive", action="store_true",
                     help="query-adaptive serving (needs --two-stage): "
                          "under queue pressure each flush degrades along "
@@ -95,12 +92,12 @@ def main():
                          "degraded/shed markers")
     ap.add_argument("--cache-entries", type=int, default=0,
                     help="snapshot-versioned exact result cache capacity "
-                         "(needs --two-stage --async; 0 disables). Delta "
+                         "(needs --two-stage; 0 disables). Delta "
                          "publications invalidate precisely: only entries "
                          "routed through dirty clusters are evicted")
     ap.add_argument("--hotset", action="store_true",
                     help="query-side heavy-hitter hot set (needs "
-                         "--two-stage --async): hot route sets' clusters "
+                         "--two-stage): hot route sets' clusters "
                          "pin into a compact fast tier served through the "
                          "fused kernel dispatcher, bit-identical to the "
                          "full store")
@@ -115,7 +112,7 @@ def main():
                     help="floor of the plan ladder's rerank-depth "
                          "halvings (degradation never reranks shallower)")
     ap.add_argument("--checkpoint-dir", default="",
-                    help="arm crash-safe streaming (needs --async): "
+                    help="arm crash-safe streaming: "
                          "write-ahead journal + full/delta engine "
                          "checkpoints under this directory; a non-empty "
                          "directory is RECOVERED from first")
@@ -128,7 +125,7 @@ def main():
                          "replay on recovery, more checkpoint writes")
     ap.add_argument("--reconcile-every", type=int, default=4,
                     help="ingest batches between snapshot publications "
-                         "(sharded reconcile / async publish cadence)")
+                         "(publish cadence; sharded: delta reconcile)")
     ap.add_argument("--metrics-json", default="",
                     help="enable telemetry and dump the metrics registry "
                          "as JSON to this path on exit")
@@ -161,7 +158,6 @@ def main():
     from repro.obs.report import Reporter
     from repro.serve.durability import DurabilityConfig
     from repro.serve.runtime import AsyncServer, ServerConfig
-    from repro.serve.server import RAGServer
 
     if args.metrics_json or args.trace_out:
         obs.enable(metrics=bool(args.metrics_json),
@@ -183,15 +179,8 @@ def main():
     assert not (args.cache_entries or args.hotset) or args.two_stage, \
         "--cache-entries/--hotset require --two-stage (cached answers " \
         "record routed clusters)"
-    assert not (args.cache_entries or args.hotset) or args.async_serve, \
-        "--cache-entries/--hotset require --async (the cache is exact " \
-        "only over published snapshots)"
     assert args.cache_entries >= 0, "--cache-entries must be >= 0"
     assert args.pin_budget_mb > 0, "--pin-budget-mb must be positive"
-    assert not (args.checkpoint_dir or args.journal_dir) \
-        or args.async_serve, \
-        "--checkpoint-dir/--journal-dir require --async (durability " \
-        "journals the background ingest path)"
     assert not args.journal_dir or args.checkpoint_dir, \
         "--journal-dir is an override of --checkpoint-dir's default"
     assert args.checkpoint_every >= 1, "--checkpoint-every must be >= 1"
@@ -218,24 +207,17 @@ def main():
         mesh = make_mesh(mesh_shape, ("data", "model"))
         engine = ShardedEngine(
             cfg, mesh, jax.random.key(0), warmup=warm,
-            # async: the runtime's publish cadence drives (delta) reconcile
-            reconcile_every=10**9 if args.async_serve
-            else args.reconcile_every,
-            reconcile_mode="delta" if args.async_serve else "full")
-    if args.async_serve:
-        server = AsyncServer(cfg, scfg, jax.random.key(0), warmup=warm,
-                             engine=engine,
-                             publish_every=args.reconcile_every,
-                             durability=durability)
-        rep = server.recovery_report
-        if rep is not None:
-            print(f"recovered        : checkpoint_seq={rep['checkpoint_seq']} "
-                  f"replayed={rep['replayed']} batches "
-                  f"({rep['docs_replayed']} docs) "
-                  f"quarantined={rep['quarantined']}")
-    else:
-        server = RAGServer(cfg, scfg, jax.random.key(0), warmup=warm,
-                           engine=engine)
+            # the server's publish cadence drives (delta) reconcile
+            reconcile_every=10**9, reconcile_mode="delta")
+    server = AsyncServer(cfg, scfg, jax.random.key(0), warmup=warm,
+                         engine=engine, publish_every=args.reconcile_every,
+                         durability=durability)
+    rep = server.recovery_report
+    if rep is not None:
+        print(f"recovered        : checkpoint_seq={rep['checkpoint_seq']} "
+              f"replayed={rep['replayed']} batches "
+              f"({rep['docs_replayed']} docs) "
+              f"quarantined={rep['quarantined']}")
 
     # SIGTERM = graceful drain: finish the current round, skip the rest
     # of the stream, then fall through to the normal shutdown path
@@ -263,13 +245,11 @@ def main():
 
     # Shutdown: drain the WHOLE pending queue (one flush answers at most
     # max_batch and would silently drop the rest).
-    if args.async_serve:
-        server.sync()            # final publish covers the stream tail
+    server.sync()            # final publish covers the stream tail
     answered += len(server.drain())
     reporter.final(submitted, answered)
     assert answered == submitted, "shutdown drain lost queries"
-    if args.async_serve:
-        server.close()   # durable: final blocking checkpoint + truncation
+    server.close()   # durable: final blocking checkpoint + truncation
     print(f"index size       : {server.engine.index_size()} prototypes")
     if durability is not None:
         rs = server.robustness_stats()

@@ -21,8 +21,8 @@ def _fmt(v, digits=2) -> str:
 
 
 class Reporter:
-    """Periodic one-line serving report + final summary for a server
-    built on ``serve.runtime.QueryFrontend`` (sync or async)."""
+    """Periodic one-line serving report + final summary for a
+    ``serve.runtime.AsyncServer``."""
 
     def __init__(self, server, every: int = 10, out=print):
         self.server = server
@@ -46,19 +46,15 @@ class Reporter:
             f"q_p99={_fmt(lat['answer_p99_ms'])}ms",
             f"batch_p50={_fmt(lat['p50_ms'])}ms",
         ]
-        fresh = getattr(self.server, "freshness_stats", None)
-        if fresh is not None:
-            f = fresh()
-            parts.append(f"snap=v{f['snapshot_version']}")
-            parts.append(f"lag={f['lag_docs']}docs")
-            if f.get("snapshot_age_s") is not None:
-                parts.append(f"age={_fmt(f['snapshot_age_s'])}s")
-        cache = getattr(self.server, "cache_stats", None)
-        if cache is not None:
-            c = cache()
-            if c["enabled"]:
-                parts.append(f"hit={_fmt(c['hit_rate'])}")
-                parts.append(f"pin={c['pinned_bytes'] // 1024}KiB")
+        f = self.server.freshness_stats()
+        parts.append(f"snap=v{f['snapshot_version']}")
+        parts.append(f"lag={f['lag_docs']}docs")
+        if f["snapshot_age_s"] is not None:
+            parts.append(f"age={_fmt(f['snapshot_age_s'])}s")
+        c = self.server.cache_stats()
+        if c["enabled"]:
+            parts.append(f"hit={_fmt(c['hit_rate'])}")
+            parts.append(f"pin={c['pinned_bytes'] // 1024}KiB")
         reg = obs.metrics()
         if reg is not None:
             snap = reg.snapshot()["gauges"]
@@ -83,21 +79,17 @@ class Reporter:
             f"p90={lat['answer_p90_ms']:.2f} "
             f"p99={lat['answer_p99_ms']:.2f} "
             f"(window={lat['answer_window']})")
-        fresh = getattr(self.server, "freshness_stats", None)
-        if fresh is not None:
-            f = fresh()
-            age = (f"{f['snapshot_age_s']:.3f}s"
-                   if f.get("snapshot_age_s") is not None else "n/a")
-            self.out(f"freshness        : snapshot v{f['snapshot_version']} "
-                     f"lag={f['lag_docs']} docs age={age}")
-        cache = getattr(self.server, "cache_stats", None)
-        if cache is not None:
-            c = cache()
-            if c["enabled"]:
-                self.out(
-                    f"serving cache    : hit_rate={c['hit_rate']:.3f} "
-                    f"hits={c['hits']} misses={c['misses']} "
-                    f"invalidated={c['invalidated']} "
-                    f"staleness={c['hit_staleness']:.2f} "
-                    f"pin={c['pinned_bytes'] // 1024}KiB "
-                    f"hot_served={c['hot_served']}")
+        f = self.server.freshness_stats()
+        age = (f"{f['snapshot_age_s']:.3f}s"
+               if f["snapshot_age_s"] is not None else "n/a")
+        self.out(f"freshness        : snapshot v{f['snapshot_version']} "
+                 f"lag={f['lag_docs']} docs age={age}")
+        c = self.server.cache_stats()
+        if c["enabled"]:
+            self.out(
+                f"serving cache    : hit_rate={c['hit_rate']:.3f} "
+                f"hits={c['hits']} misses={c['misses']} "
+                f"invalidated={c['invalidated']} "
+                f"staleness={c['hit_staleness']:.2f} "
+                f"pin={c['pinned_bytes'] // 1024}KiB "
+                f"hot_served={c['hot_served']}")

@@ -1,11 +1,10 @@
 """Serving layer: micro-batching query front end over the engine protocol.
 
-``server.RAGServer``   — synchronous event loop (ingest interleaved with
-                         query rounds on the caller's thread).
-``runtime.AsyncServer`` — background ingest thread + atomic snapshot
-                         publication; queries never block on ingest or
-                         reconcile. Supervised (bounded restarts, poison
-                         quarantine) and optionally durable.
+``runtime.AsyncServer`` — the server: background ingest thread + atomic
+                         snapshot publication; queries never block on
+                         ingest or reconcile. Supervised (bounded
+                         restarts, poison quarantine) and optionally
+                         durable.
 ``hotset.HotSet``       — query-side heavy-hitter hot set + pinned
                          fast-tier serving (Level 1 of the serving cache).
 ``result_cache.ResultCache`` — snapshot-versioned exact result cache with
@@ -21,4 +20,3 @@ from repro.serve.durability import (CheckpointStore,  # noqa: F401
 from repro.serve.hotset import HotSet  # noqa: F401
 from repro.serve.result_cache import ResultCache  # noqa: F401
 from repro.serve.runtime import AsyncServer, ServerConfig  # noqa: F401
-from repro.serve.server import RAGServer  # noqa: F401

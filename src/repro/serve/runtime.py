@@ -1,10 +1,8 @@
-"""Async serving runtime: background ingest/reconcile with snapshot swaps.
+"""The serving runtime: background ingest with snapshot swaps, and the
+micro-batching query front end that answers from the snapshots.
 
-``RAGServer`` interleaves ingest and query on one thread, so every query
-pays for the ingest dispatch (and, sharded, the reconcile) that happens
-to sit in front of it. ``AsyncServer`` decouples the two paths — the
-paper's "index refresh without interrupting queries" as an actual server
-shape:
+``AsyncServer`` decouples the ingest and query paths — the paper's
+"index refresh without interrupting queries" as an actual server shape:
 
   * a background **ingest thread** drains a bounded stream queue into the
     engine (single-device ``Engine`` or mesh-backed ``ShardedEngine``)
@@ -17,16 +15,10 @@ shape:
     (snapshots are functionally constructed; the swap is a single Python
     reference assignment).
 
-The front end itself (tickets, batching, drain, latency accounting) is
-shared: ``serve.server.RAGServer`` re-bases on ``QueryFrontend`` with a
-live-state query path, so the sync and async servers differ only in
-where answers come from.
-
 Freshness is explicit, not accidental: ``freshness_stats()`` reports the
 doc lag between what was ingested and what the published snapshot
 serves, and every answer carries the ``snapshot_version`` it was served
-from — the latency/freshness trade ``benchmarks/table16_async_serving``
-measures.
+from.
 
 Retrieval effort is a per-flush :class:`~repro.engine.plan.QueryPlan`
 (two-stage serving): every flush picks (nprobe, rerank depth, shed)
@@ -85,7 +77,7 @@ class ServerConfig:
     recover_after: int = 4      # calm flushes required to step back up
     min_depth: int = 1          # floor of the depth ladder
     min_nprobe: int = 1         # floor of the nprobe ladder
-    # ---- hot-set serving cache (two_stage + AsyncServer only) ----
+    # ---- hot-set serving cache (two_stage only) ----
     # cache_entries > 0 arms the snapshot-versioned exact result cache
     # (``serve.result_cache``): repeat queries answer from recorded exact
     # results, delta publications invalidate only entries routed through
@@ -145,19 +137,36 @@ class _Transfers(threading.local):
     n = 0
 
 
-class QueryFrontend:
-    """Micro-batching query front end shared by the sync and async servers.
+class AsyncServer:
+    """Background-ingest serving runtime over any engine.
 
-    Subclasses implement ``_query_batch(q) -> (scores, rows, ids, labels)``
-    (and may override ``_batch_meta()`` to tag answers). Tickets are
-    monotone for the life of the server — they never restart after a
-    flush — and each answer dict carries its ``ticket`` so callers can
-    join answers back to submissions.
+    ``ingest`` enqueues a stream batch and returns immediately (bounded
+    queue — a full queue applies backpressure by blocking the producer,
+    never the query path). The ingest thread drains the queue into the
+    engine and publishes a snapshot every ``publish_every`` batches; the
+    final publish on ``close``/``sync`` covers the tail. ``flush``
+    answers from the snapshot reference it reads once per batch, so a
+    concurrent publish can never tear an in-flight answer.
+
+    For a ``ShardedEngine``, construct it with a huge ``reconcile_every``
+    and let the runtime's publish cadence drive reconciliation (pass
+    ``reconcile_mode="delta"`` to amortize frequent publishes).
+
+    Tickets are monotone for the life of the server — they never
+    restart after a flush — and each answer dict carries its
+    ``ticket`` so callers can join answers back to submissions.
     """
 
+    _STOP = object()
+
     def __init__(self, cfg: pipeline.PipelineConfig,
-                 server_cfg: ServerConfig,
-                 embed_fn: Callable[[np.ndarray], np.ndarray] | None = None):
+                 server_cfg: ServerConfig, key: jax.Array | None = None,
+                 warmup=None,
+                 embed_fn: Callable[[np.ndarray], np.ndarray] | None = None,
+                 engine=None, publish_every: int = 4, queue_max: int = 64,
+                 durability: DurabilityConfig | None = None,
+                 max_restarts: int = 8, backoff_base_s: float = 0.01,
+                 backoff_max_s: float = 1.0, supervise_seed: int = 0):
         if server_cfg.two_stage:  # fail at construction, not first flush
             assert cfg.store_depth > 0, \
                 "two_stage serving needs a PipelineConfig with store_depth > 0"
@@ -207,11 +216,87 @@ class QueryFrontend:
             "answer_latency_ms":
                 collections.deque(maxlen=server_cfg.latency_window),
         }
+        if engine is not None:
+            assert engine.cfg == cfg, "engine.cfg disagrees with cfg"
+        else:
+            assert key is not None, "either an engine or an init key"
+            engine = Engine(cfg, key, warmup)
+        self.engine = engine
+        self.publish_every = max(1, publish_every)
+        # ---- supervision + durability (crash-safe streaming) ----
+        self.max_restarts = max_restarts
+        self._backoff = (backoff_base_s, backoff_max_s)
+        self._jitter = random.Random(supervise_seed)
+        self.restarts = 0
+        self.quarantined: list[int] = []   # poison-batch seqs (never silent)
+        self._attempts: dict[int, int] = {}
+        self._quarantine_after = (durability.quarantine_after
+                                  if durability is not None else 3)
+        self._error_seq: int | None = None
+        self._inflight = None              # ingest-thread resume state
+        self._inflight_stage = "done"
+        self._next_seq = 0                 # non-durable seq counter
+        self._ingest_lock = threading.Lock()  # journal order == queue order
+        self.recovery_report: dict | None = None
+        self._docs_ingested = 0             # ingest-thread private
+        self._durable = (DurableIngest(
+            durability, cluster_axis=getattr(engine, "ckpt_cluster_axis", 0))
+            if durability is not None else None)
+        if self._durable is not None and self._durable.needs_recovery():
+            self._recover()  # before the first publish: the initial
+            #                  snapshot already serves the recovered stream
+        # ---- hot-set serving cache (built BEFORE the first publish so
+        # no publication can ever race their creation) ----
+        self._result_cache = (ResultCache(server_cfg.cache_entries)
+                              if server_cfg.cache_entries > 0 else None)
+        self._hotset = (HotSet(
+            cfg, max_batch=server_cfg.max_batch,
+            pin_budget_bytes=int(server_cfg.pin_budget_mb * 2**20),
+            capacity=server_cfg.hotset_capacity,
+            refresh_every=server_cfg.hotset_refresh,
+            min_count=server_cfg.hotset_min_count)
+            if server_cfg.hotset else None)
+        # publish events (version, dirty-cluster array) cross from the
+        # ingest thread to the query path through this deque (GIL-atomic
+        # append/popleft); the query path applies them IN ORDER up to the
+        # snapshot version it pinned, so invalidation can neither run
+        # ahead of the snapshot a flush serves from nor miss a publish.
+        self._pub_events: collections.deque = collections.deque()
+        self._snapshot = engine.publish()   # queries never see None
+        self._published_docs = self._docs_ingested  # recovery is published
+        self._since_publish = 0
+        self._error: BaseException | None = None
+        self._queue: queue.Queue = queue.Queue(maxsize=max(1, queue_max))
+        # Serializes DISPATCH (not execution) between the ingest thread
+        # and the query path: concurrently enqueueing two multi-device
+        # programs from two threads can interleave their per-device
+        # enqueue order and stall a collective behind the other program
+        # on some devices. Dispatch is asynchronous, so the section is
+        # held only for enqueue time; execution still overlaps, and the
+        # query path never waits for ingest to *finish* — only for its
+        # enqueue. The two-queue priority executor replaces the old
+        # plain lock: a queued query flush always dispatches before a
+        # queued ingest/publish dispatch, so under load queries never
+        # wait behind a backlog of ingest enqueues.
+        self._dispatch = PriorityDispatcher()
+        self._closed = False
+        self._stop_sent = False
+        self._thread = threading.Thread(
+            target=self._ingest_loop, name="rag-ingest", daemon=True)
+        self._thread.start()
 
     # ----------------------------------------------------------------- query
     def submit(self, query) -> int:
         """Queue one query (text if embed_fn is set, else an embedding).
-        Returns a monotonically increasing ticket id."""
+        Returns a monotonically increasing ticket id. Raises eagerly — a
+        clear RuntimeError after ``close()`` (a post-close submission
+        could never be answered) and the stored ingest-thread error
+        (with its batch seq) instead of letting a doomed ticket queue up."""
+        if self._closed:
+            raise RuntimeError(
+                "server is closed: submit() after close() would never "
+                "be answered")
+        self._check()
         with self._lock:
             ticket = self._next_ticket
             self._next_ticket += 1
@@ -246,6 +331,10 @@ class QueryFrontend:
         carry ``degraded`` (effort below full, including shed) and
         ``plan`` so callers can audit what they got.
         """
+        # surface a dead ingest thread on the next flush too — not just
+        # lazily from sync()/close() — so callers polling the query path
+        # learn about the failed batch immediately
+        self._check()
         with self._lock:
             if not self._pending:
                 return []
@@ -394,8 +483,7 @@ class QueryFrontend:
         def pct(a, q):
             return float(np.percentile(a, q)) if a.size else 0.0
 
-        cache = getattr(self, "_result_cache", None)
-        hotset = getattr(self, "_hotset", None)
+        cache, hotset = self._result_cache, self._hotset
         return {
             "batches": n,
             "mean_ms": lat_sum / n if n else 0.0,
@@ -417,8 +505,7 @@ class QueryFrontend:
         """Serving-cache observability with a consistent zero-safe schema
         whether or not either cache level is enabled (and at any point in
         the server lifecycle — empty windows report zeros, never raise)."""
-        cache = getattr(self, "_result_cache", None)
-        hotset = getattr(self, "_hotset", None)
+        cache, hotset = self._result_cache, self._hotset
         out = {
             "enabled": cache is not None or hotset is not None,
             "hits": 0, "misses": 0, "hit_rate": 0.0, "entries": 0,
@@ -447,110 +534,6 @@ class QueryFrontend:
         host, n = fetch_answers(arrays)
         self._transfers.n += n
         return host
-
-    # ------------------------------------------------------------- interface
-    def _query_batch(self, q: np.ndarray, plan=None):
-        raise NotImplementedError
-
-    def _batch_meta(self) -> dict:
-        return {}
-
-
-class AsyncServer(QueryFrontend):
-    """Background-ingest serving runtime over any engine.
-
-    ``ingest`` enqueues a stream batch and returns immediately (bounded
-    queue — a full queue applies backpressure by blocking the producer,
-    never the query path). The ingest thread drains the queue into the
-    engine and publishes a snapshot every ``publish_every`` batches; the
-    final publish on ``close``/``sync`` covers the tail. ``flush``
-    answers from the snapshot reference it reads once per batch, so a
-    concurrent publish can never tear an in-flight answer.
-
-    For a ``ShardedEngine``, construct it with a huge ``reconcile_every``
-    and let the runtime's publish cadence drive reconciliation (pass
-    ``reconcile_mode="delta"`` to amortize frequent publishes).
-    """
-
-    _STOP = object()
-
-    def __init__(self, cfg: pipeline.PipelineConfig,
-                 server_cfg: ServerConfig, key: jax.Array | None = None,
-                 warmup=None,
-                 embed_fn: Callable[[np.ndarray], np.ndarray] | None = None,
-                 engine=None, publish_every: int = 4, queue_max: int = 64,
-                 durability: DurabilityConfig | None = None,
-                 max_restarts: int = 8, backoff_base_s: float = 0.01,
-                 backoff_max_s: float = 1.0, supervise_seed: int = 0):
-        super().__init__(cfg, server_cfg, embed_fn)
-        if engine is not None:
-            assert engine.cfg == cfg, "engine.cfg disagrees with cfg"
-        else:
-            assert key is not None, "either an engine or an init key"
-            engine = Engine(cfg, key, warmup)
-        self.engine = engine
-        self.publish_every = max(1, publish_every)
-        # ---- supervision + durability (crash-safe streaming) ----
-        self.max_restarts = max_restarts
-        self._backoff = (backoff_base_s, backoff_max_s)
-        self._jitter = random.Random(supervise_seed)
-        self.restarts = 0
-        self.quarantined: list[int] = []   # poison-batch seqs (never silent)
-        self._attempts: dict[int, int] = {}
-        self._quarantine_after = (durability.quarantine_after
-                                  if durability is not None else 3)
-        self._error_seq: int | None = None
-        self._inflight = None              # ingest-thread resume state
-        self._inflight_stage = "done"
-        self._next_seq = 0                 # non-durable seq counter
-        self._ingest_lock = threading.Lock()  # journal order == queue order
-        self.recovery_report: dict | None = None
-        self._docs_ingested = 0             # ingest-thread private
-        self._durable = (DurableIngest(
-            durability, cluster_axis=getattr(engine, "ckpt_cluster_axis", 0))
-            if durability is not None else None)
-        if self._durable is not None and self._durable.needs_recovery():
-            self._recover()  # before the first publish: the initial
-            #                  snapshot already serves the recovered stream
-        # ---- hot-set serving cache (built BEFORE the first publish so
-        # no publication can ever race their creation) ----
-        self._result_cache = (ResultCache(server_cfg.cache_entries)
-                              if server_cfg.cache_entries > 0 else None)
-        self._hotset = (HotSet(
-            cfg, max_batch=server_cfg.max_batch,
-            pin_budget_bytes=int(server_cfg.pin_budget_mb * 2**20),
-            capacity=server_cfg.hotset_capacity,
-            refresh_every=server_cfg.hotset_refresh,
-            min_count=server_cfg.hotset_min_count)
-            if server_cfg.hotset else None)
-        # publish events (version, dirty-cluster array) cross from the
-        # ingest thread to the query path through this deque (GIL-atomic
-        # append/popleft); the query path applies them IN ORDER up to the
-        # snapshot version it pinned, so invalidation can neither run
-        # ahead of the snapshot a flush serves from nor miss a publish.
-        self._pub_events: collections.deque = collections.deque()
-        self._snapshot = engine.publish()   # queries never see None
-        self._published_docs = self._docs_ingested  # recovery is published
-        self._since_publish = 0
-        self._error: BaseException | None = None
-        self._queue: queue.Queue = queue.Queue(maxsize=max(1, queue_max))
-        # Serializes DISPATCH (not execution) between the ingest thread
-        # and the query path: concurrently enqueueing two multi-device
-        # programs from two threads can interleave their per-device
-        # enqueue order and stall a collective behind the other program
-        # on some devices. Dispatch is asynchronous, so the section is
-        # held only for enqueue time; execution still overlaps, and the
-        # query path never waits for ingest to *finish* — only for its
-        # enqueue. The two-queue priority executor replaces the old
-        # plain lock: a queued query flush always dispatches before a
-        # queued ingest/publish dispatch, so under load queries never
-        # wait behind a backlog of ingest enqueues.
-        self._dispatch = PriorityDispatcher()
-        self._closed = False
-        self._stop_sent = False
-        self._thread = threading.Thread(
-            target=self._ingest_loop, name="rag-ingest", daemon=True)
-        self._thread.start()
 
     # ---------------------------------------------------------- ingest thread
     def _ingest_loop(self):
@@ -817,25 +800,6 @@ class AsyncServer(QueryFrontend):
             reg.counter("ingest_docs_enqueued_total").inc(live)
             reg.gauge("ingest_queue_depth").set(self._queue.qsize())
 
-    def submit(self, query) -> int:
-        """Queue one query. Raises eagerly — a clear RuntimeError after
-        ``close()`` (a post-close submission could never be answered)
-        and the stored ingest-thread error (with its batch seq) instead
-        of letting a doomed ticket queue up."""
-        if self._closed:
-            raise RuntimeError(
-                "server is closed: submit() after close() would never "
-                "be answered")
-        self._check()
-        return super().submit(query)
-
-    def flush(self) -> list[dict]:
-        # surface a dead ingest thread on the next flush too — not just
-        # lazily from sync()/close() — so callers polling the query path
-        # learn about the failed batch immediately
-        self._check()
-        return super().flush()
-
     def _query_batch(self, q: np.ndarray, plan=None):
         self._check()
         snap = self._snapshot        # pin ONE snapshot for the whole batch
@@ -981,12 +945,10 @@ class AsyncServer(QueryFrontend):
         return {"snapshot_version": snap.version}
 
     def serve_round(self, stream_batch=None) -> list[dict]:
-        """Event-loop-compatible turn: answer due queries FIRST (from the
+        """One event-loop turn: answer due queries FIRST (from the
         published snapshot — the devices are not yet busy with this
         round's ingest), then enqueue the stream batch for background
-        ingestion. The opposite order of ``RAGServer.serve_round``, and
-        the reason queries here never pay for ingest: the interleaved
-        loop ingests in front of every flush by construction."""
+        ingestion, so a query never waits behind its round's ingest."""
         outs = self.flush() if self._flush_due() else []
         if stream_batch is not None:
             self.ingest(stream_batch["embedding"], stream_batch["doc_id"])

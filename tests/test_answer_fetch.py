@@ -9,7 +9,7 @@ only then reads them. These tests pin:
   stores, batch sizes 1, 7 and 32, a snapshot with dead slots (the
   dead-entry score sentinel and -1 ids), the prototype-only path and the
   cached path; NaN, -inf and -0.0 bit patterns through the fetch itself;
-* host arrays (the shed answer, cached hits, host-only front ends) pass
+* host arrays (the shed answer, cached hits, host-only engines) pass
   through untouched and count no transfer;
 * the ``flush.fetch`` span's ``transfers`` arg, and that an uncached
   flush starts all three transfers before it reads any of them.
@@ -23,9 +23,8 @@ from repro import obs
 from repro.configs.streaming_rag import paper_pipeline_config
 from repro.data.streams import make_stream
 from repro.kernels.common import NEG_INF
-from repro.serve.runtime import (AsyncServer, QueryFrontend, ServerConfig,
-                                 fetch_answers)
-from repro.serve.server import RAGServer
+from repro.engine.engine import ServingSnapshot
+from repro.serve.runtime import AsyncServer, ServerConfig, fetch_answers
 
 DIM = 32
 TOPK = 5
@@ -129,16 +128,15 @@ def test_prototype_only_flush_bit_identical():
 
 
 def test_sync_server_flush_bit_identical_to_live_query():
-    cfg = _cfg("fp32")
-    stream = make_stream("iot", dim=DIM)
-    srv = RAGServer(cfg, ServerConfig(max_batch=32, max_wait_ms=0.0,
-                                      topk=TOPK, two_stage=True,
-                                      nprobe=NPROBE), jax.random.key(0))
-    b = stream.next_batch(8)
-    srv.ingest(b["embedding"], b["doc_id"])
-    qs = np.asarray(stream.queries(7)["embedding"], np.float32)
-    _assert_identical(_flush(srv, qs), srv.engine.query(
-        qs, TOPK, two_stage=True, nprobe=NPROBE))
+    """After ``sync()`` the published snapshot equals the live state, so
+    a flush answers bit-identically to the engine's live ``query``."""
+    srv, pool = _server("fp32")
+    try:
+        qs = pool[:7]
+        _assert_identical(_flush(srv, qs), srv.engine.query(
+            qs, TOPK, two_stage=True, nprobe=NPROBE))
+    finally:
+        srv.close()
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.int32])
@@ -200,23 +198,37 @@ def test_uncached_flush_overlaps_its_transfers(served, monkeypatch):
     assert fetch["args"]["transfers"] == 3
 
 
-class _HostFrontend(QueryFrontend):
-    """Answers made on the host: nothing to transfer."""
+class _HostEngine:
+    """An engine whose answers are made on the host: nothing to
+    transfer."""
 
-    def _query_batch(self, q, plan=None):
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.version = 0
+
+    def publish(self):
+        self.version += 1
+        return ServingSnapshot(None, None, None, version=self.version)
+
+    def query_snapshot(self, snap, q, k, *, two_stage, nprobe, plan=None):
         b = q.shape[0]
-        ids = np.tile(np.arange(TOPK, dtype=np.int32), (b, 1))
-        return np.zeros((b, TOPK), np.float32), ids, ids, ids
+        ids = np.tile(np.arange(k, dtype=np.int32), (b, 1))
+        return np.zeros((b, k), np.float32), ids, ids, ids
 
 
 def test_host_answers_count_no_transfer():
-    fe = _HostFrontend(_cfg("int8"), ServerConfig(max_batch=4, topk=TOPK))
-    obs.enable(metrics=False, trace=True)
-    fe.submit(np.zeros(DIM, np.float32))
-    (o,) = fe.flush()
-    assert o["doc_ids"].tolist() == list(range(TOPK))
-    (fetch,) = _spans("flush.fetch")
-    assert fetch["args"]["transfers"] == 0
+    cfg = _cfg("int8")
+    srv = AsyncServer(cfg, ServerConfig(max_batch=4, topk=TOPK),
+                      engine=_HostEngine(cfg))
+    try:
+        obs.enable(metrics=False, trace=True)
+        srv.submit(np.zeros(DIM, np.float32))
+        (o,) = srv.flush()
+        assert o["doc_ids"].tolist() == list(range(TOPK))
+        (fetch,) = _spans("flush.fetch")
+        assert fetch["args"]["transfers"] == 0
+    finally:
+        srv.close()
 
 
 def test_shed_flush_answers_host_sentinels():

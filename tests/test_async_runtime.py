@@ -32,7 +32,6 @@ from repro.core import clustering, heavy_hitter, pipeline, prefilter
 from repro.data.streams import make_stream
 from repro.engine import Engine
 from repro.serve.runtime import AsyncServer, ServerConfig
-from repro.serve.server import RAGServer
 
 DIM = 32
 WATCHDOG_S = 240.0
@@ -248,11 +247,13 @@ def test_async_ingest_thread_error_surfaces():
 def test_tickets_monotone_and_drain_answers_everything():
     cfg = small_cfg(store_depth=4)
     stream = make_stream("iot", dim=DIM)
-    server = RAGServer(cfg, ServerConfig(max_batch=4, max_wait_ms=0.0,
-                                         topk=5, two_stage=True, nprobe=4),
-                       key=jax.random.key(2))
+    server = AsyncServer(cfg, ServerConfig(max_batch=4, max_wait_ms=0.0,
+                                           topk=5, two_stage=True,
+                                           nprobe=4),
+                         key=jax.random.key(2))
     server.ingest(stream.next_batch(64)["embedding"],
                   stream.next_batch(64)["doc_id"])
+    server.sync()                              # queries see the batch
 
     first = [server.submit(q) for q in stream.queries(10)["embedding"]]
     assert first == list(range(10))
@@ -269,6 +270,7 @@ def test_tickets_monotone_and_drain_answers_everything():
     assert [o["ticket"] for o in out2] == [10, 11, 12]
     seen = [o["ticket"] for o in out1 + rest + out2]
     assert len(seen) == len(set(seen)) == 13
+    server.close()
 
 
 def test_dead_rows_are_inert_for_retrieval_state():

@@ -11,9 +11,9 @@ import jax.numpy as jnp
 from repro.core import clustering, heavy_hitter, pipeline, prefilter
 from repro.data.streams import make_stream
 from repro.engine import Engine, stages
-from repro.engine.engine import ingest_impl, query_impl
+from repro.engine.engine import ingest_impl, snapshot_query_impl
 from repro.kernels.common import l2_normalize
-from repro.serve.server import RAGServer, ServerConfig
+from repro.serve.runtime import AsyncServer, ServerConfig
 from repro.train.checkpoint import CheckpointManager
 
 DIM = 32
@@ -66,8 +66,9 @@ def test_engine_object_matches_pipeline_api_bitwise():
 
 
 def test_unjitted_stage_composition_equals_jitted_pipeline():
-    """ingest_impl/query_impl (the raw stage compositions) produce the
-    same results as the jit-compiled public wrappers."""
+    """ingest_impl and snapshot_query_impl, run un-jitted (the raw stage
+    compositions), produce the same results as the jit-compiled public
+    wrappers."""
     cfg = small_cfg(store_depth=4)
     s = make_stream("iot", dim=DIM)
     batches = [s.next_batch(32) for _ in range(3)]
@@ -87,7 +88,10 @@ def test_unjitted_stage_composition_equals_jitted_pipeline():
 
     q = jnp.asarray(s.queries(4)["embedding"])
     out_j = pipeline.query(cfg, s_jit, q, 5, two_stage=True, nprobe=4)
-    out_r = query_impl(cfg, s_raw, q, 5, two_stage=True, nprobe=4)
+    with jax.disable_jit():
+        out_r = snapshot_query_impl(cfg, s_raw.index, s_raw.route_labels,
+                                    s_raw.store, q, 5, two_stage=True,
+                                    nprobe=4)
     np.testing.assert_array_equal(np.asarray(out_j[2]), np.asarray(out_r[2]))
     np.testing.assert_allclose(np.asarray(out_j[0]), np.asarray(out_r[0]),
                                rtol=1e-6, atol=1e-7)
@@ -172,14 +176,15 @@ def test_checkpoint_roundtrip_preserves_query_results():
 
 
 def test_server_runs_on_explicit_engine():
-    """RAGServer accepts a pre-built engine (the protocol the sharded
+    """AsyncServer accepts a pre-built engine (the protocol the sharded
     engine plugs into) and serves two-stage answers from it."""
     cfg = small_cfg(store_depth=4, update_interval=32)
     s = make_stream("iot", dim=DIM)
     eng = Engine(cfg, jax.random.key(5))
-    server = RAGServer(cfg, ServerConfig(max_batch=4, max_wait_ms=0.0,
-                                         topk=5, two_stage=True, nprobe=4),
-                       engine=eng)
+    server = AsyncServer(cfg, ServerConfig(max_batch=4, max_wait_ms=0.0,
+                                           topk=5, two_stage=True,
+                                           nprobe=4),
+                         engine=eng)
     # before any batch was answered, stats must not crash (launch/serve.py
     # reports through latency_stats for exactly this reason)
     empty = server.latency_stats()
@@ -196,7 +201,9 @@ def test_server_runs_on_explicit_engine():
         for qv in s.queries(2)["embedding"]:
             server.submit(qv)
         answered += server.serve_round(b)
+        server.sync()   # the next round's queries see this round's docs
     answered += server.flush()
+    server.close()
     assert len(answered) == 8
     assert server.engine is eng
     stats = server.latency_stats()

@@ -32,7 +32,6 @@ from repro.engine import Engine, stages
 from repro.serve.hotset import HotSet, route_signature
 from repro.serve.result_cache import ResultCache
 from repro.serve.runtime import AsyncServer, ServerConfig
-from repro.serve.server import RAGServer
 
 DIM = 32
 
@@ -265,12 +264,9 @@ def test_cache_config_guardrails():
         AsyncServer(cfg, ServerConfig(max_batch=4, topk=5,
                                       cache_entries=8),
                     key=jax.random.key(0))
-    # ...and the snapshot runtime: the sync server queries live state,
-    # which has no publish boundary to invalidate against
-    with pytest.raises(AssertionError, match="snapshot runtime"):
-        RAGServer(cfg, ServerConfig(max_batch=4, topk=5, two_stage=True,
-                                    nprobe=4, hotset=True),
-                  key=jax.random.key(0))
+    with pytest.raises(AssertionError, match="two_stage"):
+        AsyncServer(cfg, ServerConfig(max_batch=4, topk=5, hotset=True),
+                    key=jax.random.key(0))
 
 
 # ------------------------------------------------------------------- sharded

@@ -30,7 +30,8 @@ from repro.data.streams import make_stream
 from repro.engine import Engine
 from repro.obs.metrics import Registry
 from repro.obs.trace import Tracer, validate_chrome_trace
-from repro.serve.runtime import AsyncServer, QueryFrontend, ServerConfig
+from repro.engine.engine import ServingSnapshot
+from repro.serve.runtime import AsyncServer, ServerConfig
 
 DIM = 32
 WATCHDOG_S = 240.0
@@ -200,22 +201,34 @@ def test_validate_chrome_trace_flags_malformed_events():
 
 
 # --------------------------------------------------------- frontend threading
-class _FakeFrontend(QueryFrontend):
-    """Front end with a host-only query batch — isolates the threading
-    behavior of submit/flush/drain from any device work."""
+class _HostEngine:
+    """Engine with host-only answers — isolates the threading behavior
+    of the server's submit/flush/drain from any device work."""
 
-    def _query_batch(self, q, plan=None):
-        b, k = q.shape[0], self.scfg.topk
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.version = 0
+
+    def publish(self):
+        self.version += 1
+        return ServingSnapshot(None, None, None, version=self.version)
+
+    def query_snapshot(self, snap, q, k, *, two_stage, nprobe, plan=None):
+        b = q.shape[0]
         ids = np.tile(np.arange(k, dtype=np.int32), (b, 1))
         return (np.zeros((b, k), np.float32), ids, ids,
                 np.zeros((b, k), np.int32))
 
 
+def _host_server(scfg):
+    cfg = small_cfg()
+    return AsyncServer(cfg, scfg, engine=_HostEngine(cfg))
+
+
 def test_frontend_totals_exact_under_concurrent_submit_flush():
     obs.enable()  # metrics recording must not perturb exactness
-    cfg = small_cfg()
-    fe = _FakeFrontend(cfg, ServerConfig(max_batch=8, max_wait_ms=0.0,
-                                         topk=4, latency_window=64))
+    fe = _host_server(ServerConfig(max_batch=8, max_wait_ms=0.0, topk=4,
+                                   latency_window=64))
     n_submitters, per_thread = 4, 200
     answered: list[dict] = []
     alock = threading.Lock()
@@ -244,6 +257,7 @@ def test_frontend_totals_exact_under_concurrent_submit_flush():
     for t in flushers:
         t.join()
     answered.extend(fe.drain())
+    fe.close()
 
     total = n_submitters * per_thread
     tickets = sorted(a["ticket"] for a in answered)
@@ -261,8 +275,9 @@ def test_frontend_totals_exact_under_concurrent_submit_flush():
 
 
 def test_latency_stats_has_per_query_window_keys_when_empty():
-    fe = _FakeFrontend(small_cfg(), ServerConfig(max_batch=4, topk=2))
+    fe = _host_server(ServerConfig(max_batch=4, topk=2))
     lat = fe.latency_stats()
+    fe.close()
     for key in ("p90_ms", "window", "answer_p50_ms", "answer_p90_ms",
                 "answer_p99_ms", "answer_window"):
         assert key in lat

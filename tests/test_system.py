@@ -8,7 +8,7 @@ from repro.configs.streaming_rag import paper_pipeline_config
 from repro.core import heavy_hitter, pipeline
 from repro.data.qa import FactStream, exact_match
 from repro.data.streams import make_stream
-from repro.serve.server import RAGServer, ServerConfig
+from repro.serve.runtime import AsyncServer, ServerConfig
 
 DIM = 48
 
@@ -92,15 +92,17 @@ def test_index_freshness_beats_static_snapshot():
 
 def test_server_answers_while_ingesting():
     cfg, state, stream = _build()
-    server = RAGServer(cfg, ServerConfig(max_batch=8, max_wait_ms=0.0),
-                       jax.random.key(0))
+    server = AsyncServer(cfg, ServerConfig(max_batch=8, max_wait_ms=0.0),
+                         jax.random.key(0))
     answered = []
     for i in range(6):
         b = stream.next_batch(64)
         for q in stream.queries(4)["embedding"]:
             server.submit(q)
         answered += server.serve_round(b)
+        server.sync()   # the next round's queries see this round's docs
     answered += server.flush()
+    server.close()
     assert len(answered) == 24
     assert server.stats["docs"] == 6 * 64
     for a in answered:
